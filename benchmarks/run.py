@@ -975,6 +975,8 @@ def main():
                          "generation; same seed => identical "
                          "recall/avg_ops numbers across runs")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.only is not None and args.only not in FIGURES:
         # a typo'd name used to silently run *nothing*; fail loudly

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import warnings
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -337,10 +338,16 @@ class AnnEngine:
         key, fn = self._level_fn(level, topk, budget, has_filter)
         try:
             return key, self._run_tiled(fn, queries, filter)
-        except Exception:
+        except Exception as e:
             if res.pallas_failover and self._backend_eff() == "pallas":
                 # kernel path failed: fail the backend over, not the
-                # query — rebuild this rung on jnp and retry bounded
+                # query — rebuild this rung on jnp and retry bounded.
+                # Never silently: the warning names the kernel error and
+                # ``stats["failovers"]`` counts it.
+                warnings.warn(
+                    f"pallas search failed ({type(e).__name__}: {e}); "
+                    "failing over to the jnp engines", RuntimeWarning,
+                    stacklevel=3)
                 self._blacklist.add("pallas")
                 self.stats["failovers"] += 1
                 self._fns.clear()
@@ -430,6 +437,12 @@ class AnnEngine:
                              "(AnnEngine(mesh=...))")
         self._view.mark_shard_dead(*shards)
         return self
+
+    @property
+    def served(self):
+        """The index that answers searches: the sharded serving clone
+        on a mesh, else the source index."""
+        return self._view
 
     @property
     def coverage(self) -> float:

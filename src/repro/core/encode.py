@@ -30,14 +30,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import codebooks as cb
+from repro.kernels.icm_encode import ICM_PRECISION
 
 
 def encode_pq(x, C):
     """Independent per-codebook nearest codeword (exact for orthogonal
     supports).  x: (n,d), C: (K,m,d) -> (n,K) int32."""
-    # scores[k]: (n, m) = -2 x C_k^T + ||c||^2
+    # scores[k]: (n, m) = -2 x C_k^T + ||c||^2, at full f32 precision so
+    # the ICM warm start does not depend on how XLA fuses the dot
     sq = cb.codeword_sq_norms(C)                             # (K,m)
-    scores = -2.0 * jnp.einsum("nd,kmd->knm", x, C) + sq[:, None, :]
+    scores = -2.0 * jnp.einsum("nd,kmd->knm", x, C,
+                               precision=ICM_PRECISION) + sq[:, None, :]
     return jnp.argmin(scores, axis=-1).T.astype(jnp.int32)   # (n,K)
 
 
@@ -57,7 +60,8 @@ def _icm_block_jnp(x, C, sq, codes, iters: int):
             bk = jax.lax.dynamic_index_in_dim(codes, k, axis=1,
                                               keepdims=False)
             r = recon - jnp.take(Ck, bk, axis=0)
-            scores = sq[k][None, :] - 2.0 * (x - r) @ Ck.T   # (n, m)
+            scores = sq[k][None, :] - 2.0 * jnp.dot(        # (n, m)
+                x - r, Ck.T, precision=ICM_PRECISION)
             new = jnp.argmin(scores, axis=-1).astype(jnp.int32)
             codes = jax.lax.dynamic_update_slice_in_dim(
                 codes, new[:, None], k, axis=1)

@@ -17,7 +17,8 @@ from __future__ import annotations
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (AbstractMesh, AxisType, Mesh, NamedSharding,
+                          PartitionSpec as P)
 
 
 class MeshView:
@@ -46,54 +47,26 @@ def axis_size(mesh: Mesh, name: str) -> int:
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across the jax versions this repo meets: the
-    top-level API with ``check_vma`` (newer), with ``check_rep``, or the
-    ``jax.experimental.shard_map`` fallback.  Replication checking is
-    disabled uniformly — our regions end in all_gather/psum so outputs
-    *are* replicated, which older checkers cannot always prove.
-
-    ``axis_names`` (optional): the mesh axes the region is *manual*
-    over (partial-manual shard_map; the rest stay GSPMD-auto).  Newer
-    jax spells this ``axis_names={...}``, the experimental fallback
-    spells it ``auto=<complement>``."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False, **kw)
-        except TypeError:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = ({} if axis_names is None
-          else {"auto": frozenset(mesh.axis_names) - set(axis_names)})
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, **kw)
+    """``jax.shard_map`` with replication checking off: our regions end
+    in all_gather/psum, so outputs *are* replicated even where the
+    checker cannot prove it.  ``axis_names`` (optional): the mesh axes
+    the region is *manual* over (the rest stay GSPMD-auto)."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)
 
 
 def make_mesh_auto(sizes: Sequence[int], names: Sequence[str]):
-    """``jax.make_mesh`` with all-Auto axis types across jax versions:
-    newer jax needs ``axis_types=(AxisType.Auto, ...)`` for meshes whose
-    regions mix sharding constraints with shard_map; 0.4.x has neither
-    ``AxisType`` nor the ``axis_types`` parameter (its meshes are
-    implicitly auto)."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(tuple(sizes), tuple(names),
-                             axis_types=(AxisType.Auto,) * len(tuple(names)))
-    except (ImportError, TypeError, AttributeError):
-        return jax.make_mesh(tuple(sizes), tuple(names))
+    """``jax.make_mesh`` with all-Auto axis types: regions that mix
+    sharding constraints with shard_map need Auto axes, and
+    ``jax.make_mesh`` defaults to Explicit ones."""
+    return jax.make_mesh(tuple(sizes), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(tuple(names)))
 
 
 def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` across the signature change: newer
-    jax takes ``(axis_sizes, axis_names)``, older jax takes one
-    ``((name, size), ...)`` shape tuple."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
+    """A device-free ``AbstractMesh`` of the given axis sizes/names."""
+    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 def maybe(axis, dim: int, mesh: Mesh):

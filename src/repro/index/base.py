@@ -204,9 +204,10 @@ def build_lut(q, C):
     # import here would cycle when repro.index is imported first
     from repro.core import codebooks as cb
     sq = cb.codeword_sq_norms(C)                             # (K,m)
+    hi = jax.lax.Precision.HIGHEST      # f32 tables, not bf16 ones, on TPU
     if q.ndim == 1:
-        return sq - 2.0 * jnp.einsum("d,kmd->km", q, C)
-    return sq[None] - 2.0 * jnp.einsum("qd,kmd->qkm", q, C)
+        return sq - 2.0 * jnp.einsum("d,kmd->km", q, C, precision=hi)
+    return sq[None] - 2.0 * jnp.einsum("qd,kmd->qkm", q, C, precision=hi)
 
 
 def lut_sum(lut, codes, cb_mask=None):
@@ -437,7 +438,8 @@ def exact_search(queries, X, topk: int, *,
 
     def one_block(qs):
         d2 = (jnp.sum(jnp.square(qs), -1)[:, None]
-              - 2.0 * qs @ X.T + xsq)
+              - 2.0 * jnp.dot(qs, X.T, precision=jax.lax.Precision.HIGHEST)
+              + xsq)
         if pred is not None:
             d2 = jnp.where(pred[None, :], d2, jnp.inf)
         neg, idx = jax.lax.top_k(-d2, topk)

@@ -265,7 +265,7 @@ def _ivf_crude_scores(luts, cand_codes, valid, fast, *,
 
 
 def _ivf_crude_phase(qs, env, *, topk: int, n_probe: int, backend: str,
-                     block_q: int = 4, block_n: int = 128, interpret=None,
+                     block_q: int = 8, block_n: int = 128, interpret=None,
                      quantized: bool = False, code_bits: int = 8,
                      refine_cap: Optional[int] = None,
                      has_filter: bool = False):
@@ -300,7 +300,7 @@ def _ivf_crude_phase(qs, env, *, topk: int, n_probe: int, backend: str,
 
 
 def _ivf_refine_phase(carry, env, *, topk: int, backend: str,
-                      block_q: int = 4, block_n: int = 128, interpret=None,
+                      block_q: int = 8, block_n: int = 128, interpret=None,
                       quantized: bool = False, code_bits: int = 8,
                       refine_cap: Optional[int] = None,
                       has_filter: bool = False):
@@ -403,7 +403,7 @@ def ivf_ops_result(ids, dist, n_cand, n_pass, *, n: int, n_lists: int,
 
 def ivf_two_step_search(queries, codes, C, structure, ivf: IVFIndex,
                         topk: int, n_probe: int, *, backend: str = "auto",
-                        block_q: int = 4, block_n: int = 128,
+                        block_q: int = 8, block_n: int = 128,
                         interpret=None, query_chunk: Optional[int] = None,
                         refine_cap: Optional[int] = None, list_codes=None,
                         lut_dtype: str = "f32", code_bits: int = 8,
@@ -461,7 +461,7 @@ def ivf_two_step_search(queries, codes, C, structure, ivf: IVFIndex,
 
 
 def _ivf_crude_only_phase(qs, env, *, topk: int, n_probe: int,
-                          backend: str, block_q: int = 4,
+                          backend: str, block_q: int = 8,
                           block_n: int = 128, interpret=None,
                           quantized: bool = False, code_bits: int = 8,
                           has_filter: bool = False):
@@ -543,7 +543,7 @@ def ivf_phase_env(codes, C, structure, ivf: IVFIndex, *, list_codes=None,
 
 
 def ivf_phase_fns(*, topk: int, n_probe: int, backend: str,
-                  block_q: int = 4, block_n: int = 128, interpret=None,
+                  block_q: int = 8, block_n: int = 128, interpret=None,
                   quantized: bool = False, code_bits: int = 8,
                   refine_cap: Optional[int] = None,
                   crude_only: bool = False, has_filter: bool = False):
@@ -567,7 +567,7 @@ def ivf_phase_fns(*, topk: int, n_probe: int, backend: str,
 
 def ivf_crude_search(queries, codes, C, structure, ivf: IVFIndex,
                      topk: int, n_probe: int, *, backend: str = "auto",
-                     block_q: int = 4, block_n: int = 128, interpret=None,
+                     block_q: int = 8, block_n: int = 128, interpret=None,
                      query_chunk: Optional[int] = None, list_codes=None,
                      lut_dtype: str = "f32", code_bits: int = 8,
                      filter=None):
@@ -626,7 +626,7 @@ class IVFTwoStep:
     n_probe: int = 8
     topk: int = 50
     backend: str = "auto"
-    block_q: int = 4
+    block_q: int = 8
     block_n: int = 128
     interpret: Optional[bool] = None
     query_chunk: Optional[int] = None

@@ -14,6 +14,12 @@ the +inf tail and the eq. 2 threshold bootstrap, which is merged
 *before* thresholding so every shard prunes against the global
 threshold — reproduces the single-device ranking exactly.
 
+Index state (codebooks, centroids, the fast mask and sigma, the
+alive-shard mask) enters every ``shard_map`` body as an operand with a
+replicated spec, never as a closure: a region may not close over arrays
+placed on a mesh, and passing them makes the wrappers work on any mesh a
+caller builds (``jax.make_mesh``'s Explicit axes included).
+
 The shard_map bodies are jnp-only: the ``backend`` / ``interpret`` /
 tile options of the source index apply to its single-device engines and
 are intentionally not consulted here (fused-kernel sharded serving is a
@@ -111,6 +117,17 @@ def _gather_sorted(cols, axis_name: str, num_keys: int = 2):
 
 def _data_size(mesh) -> int:
     return mesh.shape["data"]
+
+
+def _bind_state(mesh, body, state, in_specs, out_specs):
+    """Jit ``body(qs, *state, *arrays)`` under ``shard_map`` with the
+    replicated ``state`` operands bound: the returned callable takes
+    ``(qs, *arrays)`` like the search entry points."""
+    state = tuple(_put(mesh, x, P()) for x in state)
+    fn = jax.jit(shard_map_compat(
+        body, mesh, in_specs=(P(),) + (P(),) * len(state) + in_specs,
+        out_specs=out_specs))
+    return lambda qs, *arrays: fn(qs, *state, *arrays)
 
 
 def _sanitize(dist):
@@ -217,14 +234,13 @@ class ShardedFlatADC(_DeadShardMixin):
         key = (topk, self._dead_key(), has_filter)
         if key in self._fns:
             return self._fns[key]
-        C, n, ns = self.C, self.n, self.ns
-        K = C.shape[0]
+        n, ns = self.n, self.ns
+        K = self.C.shape[0]
         k_loc = min(topk, ns)
         quantized = self.lut_dtype == "int8"
         code_bits = self.code_bits
-        alive = self._alive_arr()
 
-        def body(qs, codes_shard, *rest):
+        def body(qs, C, alive, codes_shard, *rest):
             si = jax.lax.axis_index("data")
             off = si * ns
             if code_bits == 4:      # nibble slab: unpack once per shard
@@ -241,10 +257,9 @@ class ShardedFlatADC(_DeadShardMixin):
             mv, mg = _gather_sorted((-neg, jnp.take(gids, li)), "data")
             return mg[:, :topk], mv[:, :topk]
 
-        specs = (P(), P("data")) + ((P("data"),) if has_filter else ())
-        fn = jax.jit(shard_map_compat(
-            body, self.mesh, in_specs=specs,
-            out_specs=(P(), P())))
+        specs = (P("data"),) + ((P("data"),) if has_filter else ())
+        fn = _bind_state(self.mesh, body, (self.C, self._alive_arr()),
+                         specs, (P(), P()))
         self._fns[key] = fn
         return fn
 
@@ -309,16 +324,13 @@ class ShardedTwoStep(_DeadShardMixin):
         key = (topk, self._dead_key(), has_filter)
         if key in self._fns:
             return self._fns[key]
-        C, n, ns = self.C, self.n, self.ns
-        K = C.shape[0]
-        fast = self.structure.fast_mask
-        sigma = self.structure.sigma
+        n, ns = self.n, self.ns
+        K = self.C.shape[0]
         k_loc = min(topk, ns)
         quantized = self.lut_dtype == "int8"
         code_bits = self.code_bits
-        alive = self._alive_arr()
 
-        def body(qs, codes_shard, *rest):
+        def body(qs, C, fast, sigma, alive, codes_shard, *rest):
             si = jax.lax.axis_index("data")
             off = si * ns
             if code_bits == 4:      # nibble slab: unpack once per shard
@@ -367,10 +379,12 @@ class ShardedTwoStep(_DeadShardMixin):
                 jnp.sum(passed.astype(jnp.float32), axis=1), "data") / n
             return mg[:, :topk], mv[:, :topk], pf
 
-        specs = (P(), P("data")) + ((P("data"),) if has_filter else ())
-        fn = jax.jit(shard_map_compat(
-            body, self.mesh, in_specs=specs,
-            out_specs=(P(), P(), P())))
+        specs = (P("data"),) + ((P("data"),) if has_filter else ())
+        st = self.structure
+        fn = _bind_state(self.mesh, body,
+                         (self.C, st.fast_mask, st.sigma,
+                          self._alive_arr()),
+                         specs, (P(), P(), P()))
         self._fns[key] = fn
         return fn
 
@@ -460,9 +474,6 @@ class ShardedIVFTwoStep(_DeadShardMixin):
         key = (topk, self._dead_key(), has_filter)
         if key in self._fns:
             return self._fns[key]
-        C, centroids = self.C, self.centroids
-        fast = self.structure.fast_mask
-        sigma = self.structure.sigma
         n_probe, Ls, max_len = self.n_probe, self.Ls, self.max_len
         refine_cap = self.refine_cap
         # a shard owns at most min(n_probe, Ls) of a query's probes:
@@ -481,9 +492,9 @@ class ShardedIVFTwoStep(_DeadShardMixin):
         cap_loc = None if cap is None else min(cap, nc_loc)
         quantized = self.lut_dtype == "int8"
         code_bits = self.code_bits
-        alive = self._alive_arr()
 
-        def body(qs, lists_sh, slab_sh, *rest):
+        def body(qs, C, centroids, fast, sigma, alive, lists_sh, slab_sh,
+                 *rest):
             si = jax.lax.axis_index("data")
             L0 = si * Ls
             nq = qs.shape[0]
@@ -592,11 +603,12 @@ class ShardedIVFTwoStep(_DeadShardMixin):
                 jnp.sum(passed.astype(jnp.float32), axis=1), "data")
             return idx, dist, n_cand, n_pass
 
-        specs = ((P(), P("data"), P("data"))
-                 + ((P(),) if has_filter else ()))
-        fn = jax.jit(shard_map_compat(
-            body, self.mesh, in_specs=specs,
-            out_specs=(P(), P(), P(), P())))
+        specs = (P("data"), P("data")) + ((P(),) if has_filter else ())
+        st = self.structure
+        fn = _bind_state(self.mesh, body,
+                         (self.C, self.centroids, st.fast_mask, st.sigma,
+                          self._alive_arr()),
+                         specs, (P(), P(), P(), P()))
         self._fns[key] = fn
         return fn
 

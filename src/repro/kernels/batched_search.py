@@ -21,12 +21,20 @@ Two kernels:
                top-k merge of the full distances.  Pruned points never
                enter the ranking.
 
-The running top-k merge sorts the concatenated (running, tile) pair with
-a two-key ``lax.sort`` on (distance, global index), which reproduces
-``jax.lax.top_k``'s lowest-index-wins tie-breaking *globally* — returned
-indices are bit-identical to a monolithic top-k over the full distance
-row, including the all-ties +inf tail when fewer than ``topk`` points
-survive the margin test.
+The running top-k merge (``stages.merge_topk``) keeps the ``topk``
+smallest (distance, global index) keys seen so far with compares and
+min/max reductions (Mosaic has no sort), and ``stages.finish_topk``
+orders them after the last point tile.  The two-key order reproduces
+``jax.lax.top_k``'s lowest-index-wins tie-breaking *globally* —
+returned indices are bit-identical to a monolithic top-k over the full
+distance row, including the all-ties +inf tail when fewer than ``topk``
+points survive the margin test.
+
+Precision: f32 one-hot dots run at ``Precision.HIGHEST``.  On the TPU
+the default f32 dot rounds its operands to bfloat16, which would round
+every LUT entry; at HIGHEST a one-hot dot returns each selected entry
+exactly, so kernel distances match the jnp gather sums up to the order
+of the K additions.
 
 Both kernels accept arbitrary (non-divisible) n and nq: inputs are
 zero-padded up to the tile grid and pad columns are masked to +inf
@@ -84,6 +92,7 @@ from repro.kernels.adc import flat_onehot
 # icm_encode, ops, and the stage objects.
 from repro.kernels.stages import (check_quantized_args as
                                   _check_quantized_args,
+                                  finish_topk as _finish_topk,
                                   init_topk as _init_topk,
                                   merge_topk as _merge_topk,
                                   pad_to as _pad_to,
@@ -91,6 +100,26 @@ from repro.kernels.stages import (check_quantized_args as
                                   _resolve_kernel_code_bits,
                                   unpack_nibble_tile as
                                   _unpack_nibble_tile)
+
+# exact one-hot selection of f32 LUT entries on the MXU (module docstring)
+_F32_DOT = jax.lax.Precision.HIGHEST
+
+
+def _merge_tile(vals_ref, idx_ref, ranked, gidx, topk: int):
+    """The per-grid-step top-k epilogue every fused kernel shares: seed
+    the carry on the first point tile, merge this tile, and order the
+    carry after the last one."""
+    ni = pl.program_id(1)
+
+    @pl.when(ni == 0)
+    def _():
+        _init_topk(vals_ref, idx_ref)
+
+    _merge_topk(vals_ref, idx_ref, ranked, gidx, topk)
+
+    @pl.when(ni == pl.num_programs(1) - 1)
+    def _():
+        _finish_topk(vals_ref, idx_ref)
 
 
 def _crude_topk_kernel(codes_ref, lut_ref, *refs,
@@ -116,7 +145,7 @@ def _crude_topk_kernel(codes_ref, lut_ref, *refs,
         onehot = flat_onehot(codes, K, m, lut.dtype)  # (blk_n, K*m)
         crude = jax.lax.dot_general(                  # (blk_q, blk_n) on MXU
             lut, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=_F32_DOT, preferred_element_type=jnp.float32)
     if want_crude:
         crude_ref, vals_ref, idx_ref = refs
         crude_ref[...] = crude
@@ -126,11 +155,7 @@ def _crude_topk_kernel(codes_ref, lut_ref, *refs,
     gidx = ni * blk_n + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_n), 1)
     masked = jnp.where(gidx < n, crude, jnp.inf)      # hide pad columns
 
-    @pl.when(ni == 0)
-    def _():
-        _init_topk(vals_ref, idx_ref)
-
-    _merge_topk(vals_ref, idx_ref, masked, gidx, topk)
+    _merge_tile(vals_ref, idx_ref, masked, gidx, topk)
 
 
 def _refine_topk_kernel(codes_ref, lut_ref, crude_ref, thr_ref,
@@ -148,18 +173,14 @@ def _refine_topk_kernel(codes_ref, lut_ref, crude_ref, thr_ref,
     onehot = flat_onehot(codes, K, m, lut.dtype)
     slow = jax.lax.dot_general(
         lut, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32_DOT, preferred_element_type=jnp.float32)
     full = crude + slow                               # eq. 1 refinement
 
     gidx = ni * blk_n + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_n), 1)
     passed = (crude < thr) & (gidx < n)               # eq. 2 margin test
     ranked = jnp.where(passed, full, jnp.inf)
 
-    @pl.when(ni == 0)
-    def _():
-        _init_topk(vals_ref, idx_ref)
-
-    _merge_topk(vals_ref, idx_ref, ranked, gidx, topk)
+    _merge_tile(vals_ref, idx_ref, ranked, gidx, topk)
 
 
 @functools.partial(jax.jit,
@@ -254,6 +275,7 @@ def _slab_distances(codes, lut, K: int, m: int):
                          lut.dtype).reshape(blk_q, blk_n, K * m)
     return jax.lax.dot_general(
         onehot, lut, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=None if quantized else _F32_DOT,
         preferred_element_type=jnp.int32 if quantized else jnp.float32)
 
 
@@ -282,11 +304,7 @@ def _ivf_crude_kernel(codes_ref, ids_ref, lut_ref, *refs,
     masked = jnp.where((ids >= 0) & (gidx < nc), crude, jnp.inf)
     crude_ref[...] = masked
 
-    @pl.when(ni == 0)
-    def _():
-        _init_topk(vals_ref, idx_ref)
-
-    _merge_topk(vals_ref, idx_ref, masked, gidx, topk)
+    _merge_tile(vals_ref, idx_ref, masked, gidx, topk)
 
 
 def _ivf_refine_kernel(codes_ref, lut_ref, crude_ref, thr_ref, vals_ref,
@@ -307,18 +325,14 @@ def _ivf_refine_kernel(codes_ref, lut_ref, crude_ref, thr_ref, vals_ref,
     passed = crude < thr                         # invalid columns are +inf
     ranked = jnp.where(passed & (gidx < nc), full, jnp.inf)
 
-    @pl.when(ni == 0)
-    def _():
-        _init_topk(vals_ref, idx_ref)
-
-    _merge_topk(vals_ref, idx_ref, ranked, gidx, topk)
+    _merge_tile(vals_ref, idx_ref, ranked, gidx, topk)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("topk", "block_q", "block_n", "interpret",
                                     "code_bits"))
 def ivf_crude_topk_pallas(cand_codes, cand_ids, lut_flat, lut_scale=None,
-                          lut_offset=None, *, topk: int, block_q: int = 4,
+                          lut_offset=None, *, topk: int, block_q: int = 8,
                           block_n: int = 128, interpret: bool = True,
                           code_bits: int = 8):
     """IVF phase 1 over the gathered candidate slab.
@@ -388,7 +402,7 @@ def ivf_crude_topk_pallas(cand_codes, cand_ids, lut_flat, lut_scale=None,
                    static_argnames=("topk", "block_q", "block_n", "interpret",
                                     "code_bits"))
 def ivf_refine_topk_pallas(cand_codes, lut_flat, crude, thresholds, *,
-                           topk: int, block_q: int = 4, block_n: int = 128,
+                           topk: int, block_q: int = 8, block_n: int = 128,
                            interpret: bool = True, code_bits: int = 8):
     """IVF phase 2 over the candidate slab.  cand_codes (nq, nc, K) int
     (packed dtypes welcome; nibble-packed (nq, nc, ceil(K/2)) under

@@ -46,11 +46,23 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.stages import pad_to
 
+# Both ICM backends score at full f32 precision: the TPU's default f32
+# dot rounds operands to bfloat16, which moves near-tie argmins, and the
+# two backends only assign the same codes if they score alike.
+ICM_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _onehot(idx, m: int, dtype):
     """(blk_n,) int32 -> (blk_n, m) one-hot; matmul-gather helper."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
     return (idx[:, None] == iota).astype(dtype)
+
+
+def _gather_rows(idx, Ck):
+    """Codeword rows ``Ck[idx]`` as a one-hot matmul; exact at HIGHEST
+    precision (one selected f32 row, the rest zeros)."""
+    return jnp.dot(_onehot(idx, Ck.shape[0], Ck.dtype), Ck,
+                   precision=ICM_PRECISION)
 
 
 def _icm_kernel(x_ref, codes0_ref, c_ref, sq_ref, out_ref, *,
@@ -59,19 +71,23 @@ def _icm_kernel(x_ref, codes0_ref, c_ref, sq_ref, out_ref, *,
     codes = codes0_ref[...].astype(jnp.int32)    # (blk_n, K) warm start
     C = c_ref[...]                               # (K, m, d) VMEM-pinned
     sq = sq_ref[...]                             # (K, m)
+    col = jax.lax.broadcasted_iota(jnp.int32, codes.shape, 1)
 
     recon = jnp.zeros_like(x)
     for k in range(K):                           # static K: unrolled
-        recon = recon + _onehot(codes[:, k], m, x.dtype) @ C[k]
+        recon = recon + _gather_rows(codes[:, k], C[k])
 
     def sweep(_, carry):
         codes, recon = carry
         for k in range(K):
-            r = recon - _onehot(codes[:, k], m, x.dtype) @ C[k]
-            scores = sq[k][None, :] - 2.0 * (x - r) @ C[k].T
+            r = recon - _gather_rows(codes[:, k], C[k])
+            scores = sq[k][None, :] - 2.0 * jax.lax.dot_general(
+                x - r, C[k], (((1,), (1,)), ((), ())),
+                precision=ICM_PRECISION)
             new = jnp.argmin(scores, axis=-1).astype(jnp.int32)
-            codes = codes.at[:, k].set(new)
-            recon = r + _onehot(new, m, x.dtype) @ C[k]
+            # column k <- new as a select (Mosaic has no scatter)
+            codes = jnp.where(col == k, new[:, None], codes)
+            recon = r + _gather_rows(new, C[k])
         return codes, recon
 
     codes, _ = jax.lax.fori_loop(0, iters, sweep, (codes, recon))

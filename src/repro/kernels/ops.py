@@ -112,7 +112,7 @@ def batched_refine_topk(codes, lut_flat, crude, thresholds, topk: int, *,
 
 
 def ivf_crude_topk(cand_codes, cand_ids, lut_flat, topk: int, *,
-                   block_q: int = 4, block_n: int = 128, interpret=None,
+                   block_q: int = 8, block_n: int = 128, interpret=None,
                    lut_scale=None, lut_offset=None, code_bits: int = 8):
     """IVF phase 1 over the gathered candidate slab: crude LUT sums +
     in-kernel running top-k of crude distances (slab positions).
@@ -132,7 +132,7 @@ def ivf_crude_topk(cand_codes, cand_ids, lut_flat, topk: int, *,
 
 
 def ivf_refine_topk(cand_codes, lut_flat, crude, thresholds, topk: int, *,
-                    block_q: int = 4, block_n: int = 128, interpret=None,
+                    block_q: int = 8, block_n: int = 128, interpret=None,
                     code_bits: int = 8):
     """IVF phase 2: fused eq. 2 test + slow-codebook sum + top-k merge
     over the candidate slab -> (dist (nq, topk), pos (nq, topk))."""
@@ -203,7 +203,7 @@ def fastscan_crude_topk(packed_codes, lut_flat, topk: int, *,
 
 
 def ivf_fastscan_crude_topk(packed_cand_codes, cand_ids, lut_flat,
-                            topk: int, *, block_q: int = 4,
+                            topk: int, *, block_q: int = 8,
                             block_n: int = 128, interpret=None,
                             lut_scale=None, lut_offset=None):
     """The 4-bit fast-scan IVF slab crude pass: ``ivf_crude_topk`` over

@@ -78,17 +78,87 @@ def pad_to(x, rows: int):
         x, [(0, rows - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
 
 
-def merge_topk(vals_ref, idx_ref, tile_vals, tile_idx, topk: int):
-    """Merge a (blk_q, blk_n) tile into the running (blk_q, topk) lists.
+def _key_less(av, ai, bv, bi):
+    """Two-key order on (distance, global index): the order in which
+    ``top_k(-dist)`` returns entries (lowest index wins a tie)."""
+    return (av < bv) | ((av == bv) & (ai < bi))
 
-    Two-key ascending sort on (distance, global index) == global
-    ``top_k(-dist)`` ordering with its lowest-index tie-break.
-    """
-    merged_v = jnp.concatenate([vals_ref[...], tile_vals], axis=1)
-    merged_i = jnp.concatenate([idx_ref[...], tile_idx], axis=1)
-    sv, si = jax.lax.sort((merged_v, merged_i), dimension=1, num_keys=2)
-    vals_ref[...] = sv[:, :topk]
-    idx_ref[...] = si[:, :topk]
+
+def _row_min_key(v, i):
+    """Per-row smallest (distance, index) key -> two (rows, 1) columns."""
+    mv = jnp.min(v, axis=1, keepdims=True)
+    mi = jnp.min(jnp.where(v == mv, i, I32_MAX), axis=1, keepdims=True)
+    return mv, mi
+
+
+def merge_topk(vals_ref, idx_ref, tile_vals, tile_idx, topk: int):
+    """Merge a (blk_q, blk_n) tile into the running (blk_q, topk) sets.
+
+    The running set is kept unsorted; ``finish_topk`` orders it once
+    after the last tile.  Each step moves the tile's best remaining key
+    into the slot of the set's worst key while it beats that key, so
+    the set ends as the ``topk`` smallest (distance, global index) keys
+    seen — ``top_k(-dist)``'s selection with its lowest-index tie-break.
+    The step count is the largest per-row number of tile keys that beat
+    the row's worst key on entry (at most ``topk``): after the first
+    tiles most rows admit nothing, and a whole tile costs one compare.
+    Built from compares, min/max reductions and selects only, which
+    Mosaic lowers (it has no sort)."""
+    rv, ri = vals_ref[...], idx_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, rv.shape, 1)
+    wv = jnp.max(rv, axis=1, keepdims=True)
+    wi = jnp.max(jnp.where(rv == wv, ri, -1), axis=1, keepdims=True)
+    beats = _key_less(tile_vals, tile_idx, wv, wi).astype(jnp.int32)
+    steps = jnp.minimum(jnp.max(jnp.sum(beats, axis=1, keepdims=True)),
+                        topk)
+
+    def step(_, carry):
+        tv, ti, rv, ri = carry
+        cv, ci = _row_min_key(tv, ti)
+        wv = jnp.max(rv, axis=1, keepdims=True)
+        wi = jnp.max(jnp.where(rv == wv, ri, -1), axis=1, keepdims=True)
+        slot = jnp.min(jnp.where((rv == wv) & (ri == wi), col, topk),
+                       axis=1, keepdims=True)
+        take = _key_less(cv, ci, wv, wi)
+        put = take & (col == slot)
+        rv = jnp.where(put, cv, rv)
+        ri = jnp.where(put, ci, ri)
+        # a taken key becomes the (inf, id_max) filler no key loses to
+        gone = take & (ti == ci)
+        tv = jnp.where(gone, jnp.inf, tv)
+        ti = jnp.where(gone, I32_MAX, ti)
+        return tv, ti, rv, ri
+
+    _, _, rv, ri = jax.lax.fori_loop(0, steps, step,
+                                     (tile_vals, tile_idx, rv, ri))
+    vals_ref[...] = rv
+    idx_ref[...] = ri
+
+
+def finish_topk(vals_ref, idx_ref):
+    """Order the running sets ascending by (distance, global index) in
+    place — a selection sort of ``topk`` steps, run once per query tile
+    after its last point tile."""
+    rv, ri = vals_ref[...], idx_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, rv.shape, 1)
+    topk = rv.shape[1]
+
+    def step(j, carry):
+        rv, ri, ov, oi = carry
+        mv, mi = _row_min_key(rv, ri)
+        slot = jnp.min(jnp.where((rv == mv) & (ri == mi), col, topk),
+                       axis=1, keepdims=True)
+        out = col == j
+        ov = jnp.where(out, mv, ov)
+        oi = jnp.where(out, mi, oi)
+        gone = col == slot
+        rv = jnp.where(gone, jnp.inf, rv)
+        ri = jnp.where(gone, I32_MAX, ri)
+        return rv, ri, ov, oi
+
+    _, _, ov, oi = jax.lax.fori_loop(0, topk, step, (rv, ri, rv, ri))
+    vals_ref[...] = ov
+    idx_ref[...] = oi
 
 
 def init_topk(vals_ref, idx_ref):

@@ -32,14 +32,10 @@ from typing import Dict, List, Optional, Tuple
 
 
 def xla_cost_analysis(compiled) -> Dict:
-    """XLA's builtin ``compiled.cost_analysis()`` across jax versions:
-    newer jax returns one flat dict, 0.4.x returns a one-element list
-    of dicts (one per partition).  Always returns a dict ({} when XLA
+    """XLA's builtin ``compiled.cost_analysis()`` as a dict ({} when XLA
     reports nothing)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    return compiled.cost_analysis() or {}
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

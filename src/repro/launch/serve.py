@@ -317,6 +317,8 @@ def main():
     ap.add_argument("--serve-seed", type=int, default=0,
                     help="--serve-loop: seed for arrivals + query rows")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     overrides = {k: v for k, v in {
         "serve.backend": args.ann_backend,

@@ -176,6 +176,8 @@ def main():
     ap.add_argument("--icq-add", type=int, default=64,
                     help="held-out rows appended via Index.add post-build")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.icq:
         run_icq(args)

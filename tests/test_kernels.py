@@ -34,9 +34,15 @@ def test_two_step_sweep(key, n, K, m, kf):
     (1024, 8, 8, 32, 10),      # divisible
     (999, 3, 2, 64, 7),        # tiny K, odd n
 ])
-def test_batched_crude_topk_sweep(key, n, nq, K, m, topk):
+@pytest.mark.parametrize("ties", [False, True])
+def test_batched_crude_topk_sweep(key, n, nq, K, m, topk, ties):
+    """``ties``: integer LUT entries, so many points share a distance
+    across point tiles and the merge must keep ``top_k``'s lowest-index
+    tie-break."""
     codes = jax.random.randint(key, (n, K), 0, m)
     luts = jax.random.normal(jax.random.fold_in(key, 1), (nq, K, m))
+    if ties:
+        luts = jnp.round(luts)
     crude, vals, idx = ops.batched_crude_topk(
         codes, luts.reshape(nq, K * m), topk, block_q=2, block_n=128,
         interpret=True)
