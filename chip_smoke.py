@@ -50,7 +50,8 @@ N_QUERIES = 256
 BATCH = 64                 # queries per search batch
 K_EVAL = 10                # recall@10
 MIN_AGREEMENT = 0.99       # id agreement with the reference engine
-MIN_RECALL = 0.05          # far above chance; catches garbage ids
+MIN_RECALL = 0.02          # 2000x chance (10 of 1M); catches garbage
+                           # ids (seed 0 reads about 0.055 on a v5e)
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
@@ -210,8 +211,8 @@ def run_one_chip(clock, cfg, learn, base, queries, gt, seed):
     import numpy as np
 
     from repro.api import icq_session
-    from repro.core.encode import icm_encode
     from repro.serve import ServingLoop, Tenant
+    from repro.trainer import encode_database
 
     session = icq_session(cfg)
     _, t = clock.run(lambda: session.fit(learn,
@@ -221,18 +222,24 @@ def run_one_chip(clock, cfg, learn, base, queries, gt, seed):
     two_step, t = clock.run(lambda: session.index(base))
     emit("index_two_step", n=two_step.n, encode_backend=cfg.encode.backend,
          **t)
-    # encode parity: the pallas ICM engine against the jnp one on the
-    # first 8192 base rows (same codebooks, same warm start)
-    rows = session.model.embed(jax.numpy.asarray(base[:8192]))
-    C = session.model.C
-    codes = {be: np.asarray(icm_encode(rows, C, cfg.encode.icm_iters,
-                                       backend=be))
-             for be in ("pallas", "jnp")}
-    stored = np.asarray(two_step.index.codes[:8192]).astype(np.int32)
-    emit("encode_parity", rows=8192,
-         row_agreement_pallas_jnp=float(np.mean(
-             np.all(codes["pallas"] == codes["jnp"], axis=1))),
-         stored_equal_pallas=bool(np.array_equal(stored, codes["pallas"])))
+    # encode parity: the first encode chunk of the base again, through
+    # the index's own encoder, on the pallas and the jnp ICM engines
+    # (same codebooks, same PQ warm start)
+    rows = session.model.embed(jax.numpy.asarray(base[:cfg.encode.chunk]))
+    codes = {be: np.asarray(encode_database(
+        rows, session.model.C, icm_iters=cfg.encode.icm_iters,
+        chunk=cfg.encode.chunk, backend=be, pack=False))
+        for be in ("pallas", "jnp")}
+    stored = np.asarray(two_step.index.codes[:cfg.encode.chunk])
+
+    def row_agreement(a, b):
+        return float(np.mean(np.all(a == b, axis=1)))
+
+    emit("encode_parity", rows=rows.shape[0],
+         row_agreement_pallas_jnp=row_agreement(codes["pallas"],
+                                                codes["jnp"]),
+         row_agreement_stored_pallas=row_agreement(
+             stored.astype(np.int32), codes["pallas"]))
 
     ids_two_step = serve_phase(clock, "search_two_step", two_step, queries,
                                gt)

@@ -17,8 +17,7 @@ from __future__ import annotations
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import (AbstractMesh, AxisType, Mesh, NamedSharding,
-                          PartitionSpec as P)
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 class MeshView:
@@ -62,11 +61,6 @@ def make_mesh_auto(sizes: Sequence[int], names: Sequence[str]):
     ``jax.make_mesh`` defaults to Explicit ones."""
     return jax.make_mesh(tuple(sizes), tuple(names),
                          axis_types=(AxisType.Auto,) * len(tuple(names)))
-
-
-def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
-    """A device-free ``AbstractMesh`` of the given axis sizes/names."""
-    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 def maybe(axis, dim: int, mesh: Mesh):

@@ -30,7 +30,7 @@ import traceback
 import jax
 
 from repro.configs import SHAPES, get_config, list_archs, shapes_for
-from repro.launch.hlo_cost import analyze_hlo, xla_cost_analysis
+from repro.launch.hlo_cost import analyze_hlo
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import lower_cell, plan_cell
 
@@ -147,7 +147,7 @@ def exec_flops(cfg, shape) -> float:
 
 def analyze(compiled, lowered, cfg, shape, mesh) -> dict:
     n_dev = mesh.devices.size
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     # trip-count-aware HLO accounting (launch.hlo_cost) — XLA's builtin
     # counts while bodies once, useless for scanned-layers programs

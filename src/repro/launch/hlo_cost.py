@@ -31,12 +31,6 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 
-def xla_cost_analysis(compiled) -> Dict:
-    """XLA's builtin ``compiled.cost_analysis()`` as a dict ({} when XLA
-    reports nothing)."""
-    return compiled.cost_analysis() or {}
-
-
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
     "s64": 8, "s32": 4, "s16": 2, "s8": 1,

@@ -201,9 +201,8 @@ def test_plan_mesh_shape(n, divisors, expect):
 # ------------------------------------------------------------- shardings --
 
 def test_param_pspec_tables(key):
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import abstract_mesh
-    mesh = abstract_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+    mesh = AbstractMesh((1, 1), ("data", "model"))
 
     class Leaf:
         def __init__(self, shape):
@@ -214,7 +213,7 @@ def test_param_pspec_tables(key):
         (jax.tree_util.DictKey("embed"),), Leaf((100, 64)), mesh)
     assert spec == P(None, None)   # axis size 1 -> replicated by guard
 
-    mesh2 = abstract_mesh((2, 2), ("data", "model"))
+    mesh2 = AbstractMesh((2, 2), ("data", "model"))
     spec2 = shrules.param_pspec(
         (jax.tree_util.DictKey("embed"),), Leaf((100, 64)), mesh2)
     assert spec2 == P("model", "data")
@@ -229,8 +228,8 @@ def test_every_smoke_param_gets_a_spec():
     (falling back to replication is fine; crashing is not)."""
     from repro.configs import list_archs, smoke_config
     from repro.models import build_model
-    from repro.distributed.sharding import abstract_mesh
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     for arch in list_archs():
         cfg = smoke_config(arch)
         model = build_model(cfg)

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.distributed.sharding import make_mesh_auto, shard_map_compat
-from repro.launch.hlo_cost import analyze_hlo, xla_cost_analysis
+from repro.launch.hlo_cost import analyze_hlo
 
 
 def _compile(f, *args):
@@ -22,7 +22,7 @@ def test_loop_free_matches_xla():
     w = jax.ShapeDtypeStruct((512, 512), jnp.float32)
     c = _compile(f, x, w)
     mine = analyze_hlo(c.as_text())
-    xla = xla_cost_analysis(c)
+    xla = c.cost_analysis()
     assert mine["flops"] == pytest.approx(xla["flops"], rel=1e-6)
     assert mine["flops"] == pytest.approx(2 * 2 * 256 * 512 * 512, rel=1e-6)
 
