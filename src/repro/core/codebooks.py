@@ -106,8 +106,14 @@ def init_residual(key, x, num_codebooks: int, m: int, iters: int = 25,
 # ------------------------------------------------------------ geometry ----
 
 def codeword_sq_norms(C):
-    """||c||^2 per codeword.  C: (K,m,d) -> (K,m)."""
-    return jnp.sum(jnp.square(C), axis=-1)
+    """||c||^2 per codeword.  C: (K,m,d) -> (K,m).
+
+    A full-precision contraction rather than a square-and-sum: XLA's CPU
+    backend reduces a fused square-and-sum in another order than an
+    unfused one, so the sum's last bit would depend on the program it is
+    traced into (eager vs jit, a query chunk vs the whole batch)."""
+    return jnp.einsum("kmd,kmd->km", C, C,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def cross_gram(C):
