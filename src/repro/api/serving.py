@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.api.artifacts import ArtifactError, Artifacts
 from repro.api.config import (ConfigError, IndexConfig, ResilienceConfig,
                               ServeConfig)
+from repro.obs import span
 from repro.resilience.budget import (DEGRADE_LEVELS, ResultMeta,
                                      SearchBudget, validate_budget)
 from repro.resilience.retry import BackoffPolicy, retry_with_backoff
@@ -277,11 +278,13 @@ class AnnEngine:
             return probe + ("crude", "refine-capped")
         return probe + ("crude", "refine")
 
-    def _attempt(self, fn, *args):
+    def _attempt(self, fn, *args, chunk: int = 0):
         if self.fault_injector is not None:
             self.fault_injector.check("engine.search")
-        r = fn(*args)
-        jax.block_until_ready((r.indices, r.distances))
+        with span("engine.dispatch", chunk=chunk):
+            r = fn(*args)
+        with span("engine.wait", chunk=chunk):
+            jax.block_until_ready((r.indices, r.distances))
         return r
 
     def _run_tiled(self, fn, queries, filter=None):
@@ -299,30 +302,33 @@ class AnnEngine:
             return self._attempt(fn, *args)
         tile = int(tile)
         parts = []
-        for s in range(0, max(nq, 1), tile):
+        for i, s in enumerate(range(0, max(nq, 1), tile)):
             chunk = queries[s:s + tile]
             pad = tile - chunk.shape[0]
             if pad:
-                chunk = jnp.concatenate(
-                    [chunk, jnp.zeros((pad, chunk.shape[1]),
-                                      dtype=chunk.dtype)], axis=0)
+                with span("engine.pad", pad=pad):
+                    chunk = jnp.concatenate(
+                        [chunk, jnp.zeros((pad, chunk.shape[1]),
+                                          dtype=chunk.dtype)], axis=0)
             args = (chunk,) if filter is None else (chunk, filter)
-            parts.append(self._attempt(fn, *args))
-        if len(parts) == 1:
-            r = parts[0]
-            ids, dists = r.indices[:nq], r.distances[:nq]
-        else:
-            r = parts[-1]
-            ids = jnp.concatenate([p.indices for p in parts], axis=0)[:nq]
-            dists = jnp.concatenate([p.distances for p in parts],
-                                    axis=0)[:nq]
-        # avg_ops/pass_rate are padded-batch diagnostics (mean over
-        # chunks); the bitwise contract covers ids + distances only
-        k = len(parts)
-        return r._replace(
-            indices=ids, distances=dists,
-            avg_ops=sum(p.avg_ops for p in parts) / k,
-            pass_rate=sum(p.pass_rate for p in parts) / k)
+            parts.append(self._attempt(fn, *args, chunk=i))
+        with span("engine.assemble"):
+            if len(parts) == 1:
+                r = parts[0]
+                ids, dists = r.indices[:nq], r.distances[:nq]
+            else:
+                r = parts[-1]
+                ids = jnp.concatenate([p.indices for p in parts],
+                                      axis=0)[:nq]
+                dists = jnp.concatenate([p.distances for p in parts],
+                                        axis=0)[:nq]
+            # avg_ops/pass_rate are padded-batch diagnostics (mean over
+            # chunks); the bitwise contract covers ids + distances only
+            k = len(parts)
+            return r._replace(
+                indices=ids, distances=dists,
+                avg_ops=sum(p.avg_ops for p in parts) / k,
+                pass_rate=sum(p.pass_rate for p in parts) / k)
 
     def _serve_with_failover(self, level, topk, budget, queries,
                              filter=None):
@@ -381,10 +387,13 @@ class AnnEngine:
         level = self._pick_level(budget)
         deadline = (budget.deadline_ms if budget.deadline_ms is not None
                     else self.resilience.deadline_ms)
-        t0 = time.perf_counter()
-        key, result = self._serve_with_failover(level, k, budget, queries,
-                                                filter)
-        wall_ms = (time.perf_counter() - t0) * 1000.0
+        # the span covers what ``wall_ms`` times
+        with span("engine.search", level=level,
+                  backend=self._backend_eff(), rows=len(queries)):
+            t0 = time.perf_counter()
+            key, result = self._serve_with_failover(level, k, budget,
+                                                    queries, filter)
+            wall_ms = (time.perf_counter() - t0) * 1000.0
         # warm-only timing: the first call through a compiled fn pays
         # tracing + compilation and would poison the ladder's estimates
         if key in self._warmed:

@@ -32,6 +32,7 @@ import numpy as np
 from repro.api.artifacts import Artifacts
 from repro.api.config import (JOINT_MODES, ConfigError, ICQConfig)
 from repro.api.serving import AnnEngine, build_index
+from repro.obs import span
 
 
 class Searcher:
@@ -65,8 +66,10 @@ class Searcher:
         it is True — absent slots come back id -1 / dist +inf.  Returns
         a ``repro.index.SearchResult`` whose ``meta`` reports what the
         engine actually did."""
-        emb = self.model.embed(jnp.asarray(queries))
-        return self.engine.search(emb, k, budget=budget, filter=filter)
+        with span("search", rows=len(queries)):
+            with span("embed"):
+                emb = self.model.embed(jnp.asarray(queries))
+            return self.engine.search(emb, k, budget=budget, filter=filter)
 
     def add(self, new_x, **encode_opts) -> "Searcher":
         """Encode raw-space ``new_x`` through the model + tiled ICM

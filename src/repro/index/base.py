@@ -203,11 +203,13 @@ def build_lut(q, C):
     # lazy: repro.core re-exports this module's names, so a module-level
     # import here would cycle when repro.index is imported first
     from repro.core import codebooks as cb
-    sq = cb.codeword_sq_norms(C)                             # (K,m)
     hi = jax.lax.Precision.HIGHEST      # f32 tables, not bf16 ones, on TPU
-    if q.ndim == 1:
-        return sq - 2.0 * jnp.einsum("d,kmd->km", q, C, precision=hi)
-    return sq[None] - 2.0 * jnp.einsum("qd,kmd->qkm", q, C, precision=hi)
+    with jax.named_scope("lut_build"):
+        sq = cb.codeword_sq_norms(C)                         # (K,m)
+        if q.ndim == 1:
+            return sq - 2.0 * jnp.einsum("d,kmd->km", q, C, precision=hi)
+        return sq[None] - 2.0 * jnp.einsum("qd,kmd->qkm", q, C,
+                                           precision=hi)
 
 
 def lut_sum(lut, codes, cb_mask=None):

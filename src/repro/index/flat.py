@@ -170,12 +170,13 @@ def _flat_crude_phase(qs, env, *, topk: int, backend: str,
                        block_n=block_n, interpret=interpret,
                        quantized=quantized, code_bits=code_bits)
     luts = build_lut(qs, env["C"])                       # (nq,K,m)
-    if backend == "pallas":
-        out = stage(env["codes"], luts, env["fast"])
-        return luts, out.crude, out.cand_vals, out.cand_idx
-    pred = env["pred"] if has_filter else None
-    out = stage(env["codes"], luts, env["fast"], pred=pred)
-    return luts, out.crude, None, None
+    with jax.named_scope("crude"):
+        if backend == "pallas":
+            out = stage(env["codes"], luts, env["fast"])
+            return luts, out.crude, out.cand_vals, out.cand_idx
+        pred = env["pred"] if has_filter else None
+        out = stage(env["codes"], luts, env["fast"], pred=pred)
+        return luts, out.crude, None, None
 
 
 def _flat_refine_phase(carry, env, *, topk: int, backend: str,
@@ -206,32 +207,35 @@ def _flat_refine_phase(carry, env, *, topk: int, backend: str,
     rstage = RefineStage(backend=backend, topk=topk, block_q=block_q,
                          block_n=block_n, interpret=interpret,
                          code_bits=code_bits)
-    if backend == "pallas":
-        thr = tstage.from_candidates(luts, codes, cand_vals, cand_idx,
-                                     fast, sigma)
-        idx, dist, passed = rstage(codes, luts, crude, thr, fast)
-        return idx, dist, jnp.mean(passed.astype(jnp.float32), axis=1)
-    thr = tstage.from_dense(luts, codes, crude, fast, sigma)
+    with jax.named_scope("threshold"):
+        if backend == "pallas":
+            thr = tstage.from_candidates(luts, codes, cand_vals, cand_idx,
+                                         fast, sigma)
+        else:
+            thr = tstage.from_dense(luts, codes, crude, fast, sigma)
     if refine_cap is None:
-        idx, dist, passed = rstage(codes, luts, crude, thr, fast,
-                                   pred=pred)
-        return idx, dist, jnp.mean(passed.astype(jnp.float32), axis=1)
+        with jax.named_scope("refine"):
+            idx, dist, passed = rstage(codes, luts, crude, thr, fast,
+                                       pred=pred)
+            return idx, dist, jnp.mean(passed.astype(jnp.float32), axis=1)
     # compact: best-crude survivors first, capped
-    passed = crude < thr[:, None]
-    masked = jnp.where(passed, crude, jnp.inf)
-    neg_s, surv = jax.lax.top_k(-masked, refine_cap)
-    valid = jnp.isfinite(-neg_s)
-    surv_codes = jnp.take(codes, surv, axis=0)           # (nq,cap,K)
-    if code_bits == 4:
-        surv_codes = _widen_codes(surv_codes, env["C"].shape[0],
-                                  code_bits)
-    full_surv = lut_sum(luts, surv_codes)
-    ranked = jnp.where(valid, full_surv, jnp.inf)
-    neg, pos = jax.lax.top_k(-ranked, topk)
-    idx = jnp.take_along_axis(surv, pos, axis=1)
-    if pred is not None:
-        idx = mask_filtered_ids(idx, -neg)
-    return idx, -neg, jnp.mean(passed.astype(jnp.float32), axis=1)
+    with jax.named_scope("refine"):
+        passed = crude < thr[:, None]
+        masked = jnp.where(passed, crude, jnp.inf)
+        neg_s, surv = jax.lax.top_k(-masked, refine_cap)
+        valid = jnp.isfinite(-neg_s)
+        surv_codes = jnp.take(codes, surv, axis=0)       # (nq,cap,K)
+        if code_bits == 4:
+            surv_codes = _widen_codes(surv_codes, env["C"].shape[0],
+                                      code_bits)
+        full_surv = lut_sum(luts, surv_codes)
+        ranked = jnp.where(valid, full_surv, jnp.inf)
+        with jax.named_scope("merge"):
+            neg, pos = jax.lax.top_k(-ranked, topk)
+            idx = jnp.take_along_axis(surv, pos, axis=1)
+            if pred is not None:
+                idx = mask_filtered_ids(idx, -neg)
+        return idx, -neg, jnp.mean(passed.astype(jnp.float32), axis=1)
 
 
 def _two_step_block_jnp(qs, codes, C, fast, sigma, topk: int,
@@ -391,15 +395,17 @@ def _flat_crude_only_phase(qs, env, *, topk: int, backend: str,
                        quantized=quantized, code_bits=code_bits,
                        want_crude=False)
     luts = build_lut(qs, env["C"])
-    if backend == "pallas":
-        out = stage(env["codes"], luts, env["fast"])
-        return (out.cand_idx, out.cand_vals,
-                jnp.zeros(qs.shape[0], dtype=jnp.float32))
-    pred = env["pred"] if has_filter else None
-    crude = stage(env["codes"], luts, env["fast"], pred=pred).crude
-    neg_c, cand = jax.lax.top_k(-crude, topk)
-    if pred is not None:
-        cand = mask_filtered_ids(cand, -neg_c)
+    with jax.named_scope("crude"):
+        if backend == "pallas":
+            out = stage(env["codes"], luts, env["fast"])
+            return (out.cand_idx, out.cand_vals,
+                    jnp.zeros(qs.shape[0], dtype=jnp.float32))
+        pred = env["pred"] if has_filter else None
+        crude = stage(env["codes"], luts, env["fast"], pred=pred).crude
+    with jax.named_scope("merge"):
+        neg_c, cand = jax.lax.top_k(-crude, topk)
+        if pred is not None:
+            cand = mask_filtered_ids(cand, -neg_c)
     return cand, -neg_c, jnp.zeros(qs.shape[0], dtype=jnp.float32)
 
 

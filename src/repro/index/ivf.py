@@ -218,7 +218,8 @@ def _ivf_bootstrap_threshold(luts, crude, cand_codes, topk: int, sigma,
     ``lut_dtype="int8"``."""
     stage = ThresholdStage(topk=topk, quantized=fast is not None,
                            code_bits=code_bits)
-    return stage.from_dense_slab(luts, cand_codes, crude, fast, sigma)
+    with jax.named_scope("threshold"):
+        return stage.from_dense_slab(luts, cand_codes, crude, fast, sigma)
 
 
 def _ivf_crude_scores(luts, cand_codes, valid, fast, *,
@@ -277,26 +278,37 @@ def _ivf_crude_phase(qs, env, *, topk: int, n_probe: int, backend: str,
     refine kernel).  The refine phase is the carry's last reader, so
     the pipelined executor donates it (DESIGN.md §13)."""
     luts = build_lut(qs, env["C"])                       # (nq, K, m)
-    probes = coarse_probe(qs, env["centroids"], n_probe)
-    cand_ids, valid, cand_codes = gather_candidates(
-        probes, env["lists"], env["codes"], topk, env["list_codes"])
-    safe = jnp.where(valid, cand_ids, 0)
+    cand_ids, valid, cand_codes, safe = _probe_and_gather(qs, env, topk,
+                                                          n_probe)
     stage = CrudeStage(backend=backend, topk=topk, block_q=block_q,
                        block_n=block_n, interpret=interpret,
                        quantized=quantized, code_bits=code_bits)
-    if backend == "pallas":
-        out = stage.slab(cand_codes, cand_ids, valid, luts, env["fast"])
-        return (luts, out.crude, out.cand_vals, out.cand_idx, None,
-                cand_codes, safe, valid)
-    pred = env["pred"] if has_filter else None
-    if pred is not None:
-        # filtered rows score +inf crude: they can't pass eq. 2, can't
-        # set the bootstrap threshold, and rank last
-        valid = valid & pred[safe]
-    out = stage.slab(cand_codes, cand_ids, valid, luts, env["fast"],
-                     need_slow=refine_cap is None)
-    return (luts, out.crude, None, None, out.slow, cand_codes, safe,
-            valid)
+    with jax.named_scope("crude"):
+        if backend == "pallas":
+            out = stage.slab(cand_codes, cand_ids, valid, luts,
+                             env["fast"])
+            return (luts, out.crude, out.cand_vals, out.cand_idx, None,
+                    cand_codes, safe, valid)
+        pred = env["pred"] if has_filter else None
+        if pred is not None:
+            # filtered rows score +inf crude: they can't pass eq. 2,
+            # can't set the bootstrap threshold, and rank last
+            valid = valid & pred[safe]
+        out = stage.slab(cand_codes, cand_ids, valid, luts, env["fast"],
+                         need_slow=refine_cap is None)
+        return (luts, out.crude, None, None, out.slow, cand_codes, safe,
+                valid)
+
+
+def _probe_and_gather(qs, env, topk: int, n_probe: int):
+    """The coarse probe and the candidate-slab gather of one query
+    tile: (cand_ids, valid, cand_codes, safe ids)."""
+    with jax.named_scope("probe"):
+        probes = coarse_probe(qs, env["centroids"], n_probe)
+    with jax.named_scope("ivf_gather"):
+        cand_ids, valid, cand_codes = gather_candidates(
+            probes, env["lists"], env["codes"], topk, env["list_codes"])
+        return cand_ids, valid, cand_codes, jnp.where(valid, cand_ids, 0)
 
 
 def _ivf_refine_phase(carry, env, *, topk: int, backend: str,
@@ -318,37 +330,42 @@ def _ivf_refine_phase(carry, env, *, topk: int, backend: str,
                          code_bits=code_bits)
     n_cand = jnp.sum(valid.astype(jnp.float32), axis=1)
     if backend == "pallas":
-        thr = tstage.from_slab_candidates(luts, cand_codes, cand_vals,
-                                          cand_pos, fast, sigma)
-        ids, dist, passed = rstage.slab(cand_codes, luts, crude, thr,
-                                        fast, safe)
-        n_pass = jnp.sum(passed.astype(jnp.float32), axis=1)
+        with jax.named_scope("threshold"):
+            thr = tstage.from_slab_candidates(luts, cand_codes, cand_vals,
+                                              cand_pos, fast, sigma)
+        with jax.named_scope("refine"):
+            ids, dist, passed = rstage.slab(cand_codes, luts, crude, thr,
+                                            fast, safe)
+            n_pass = jnp.sum(passed.astype(jnp.float32), axis=1)
         return ids, dist, n_cand, n_pass
     pred = env["pred"] if has_filter else None
-    thr = tstage.from_dense_slab(luts, cand_codes, crude,
-                                 fast if quantized else None, sigma)
-    passed = crude < thr[:, None]                        # invalid->inf->F
-    if refine_cap is None:
-        ids, dist, _ = rstage.slab(cand_codes, luts, crude, thr, fast,
-                                   safe, slow=slow, pred=pred)
-    else:
-        # clamp into [topk, nc]: the slab is padded to >= topk columns
-        cap = min(max(refine_cap, topk), crude.shape[1])
-        masked = jnp.where(passed, crude, jnp.inf)
-        neg_s, surv = jax.lax.top_k(-masked, cap)        # slab positions
-        alive = jnp.isfinite(-neg_s)
-        surv_codes = jnp.take_along_axis(cand_codes, surv[:, :, None],
-                                         axis=1)         # (nq, cap, K)
-        full_surv = lut_sum(luts, _widen_slab(surv_codes, luts.shape[1],
-                                              code_bits))
-        ranked = jnp.where(alive, full_surv, jnp.inf)
-        neg, cpos = jax.lax.top_k(-ranked, topk)
-        pos = jnp.take_along_axis(surv, cpos, axis=1)
-        ids = jnp.take_along_axis(safe, pos, axis=1)
-        dist = -neg
-        if pred is not None:
-            ids = mask_filtered_ids(ids, dist)
-    n_pass = jnp.sum(passed.astype(jnp.float32), axis=1)
+    with jax.named_scope("threshold"):
+        thr = tstage.from_dense_slab(luts, cand_codes, crude,
+                                     fast if quantized else None, sigma)
+    with jax.named_scope("refine"):
+        passed = crude < thr[:, None]                    # invalid->inf->F
+        if refine_cap is None:
+            ids, dist, _ = rstage.slab(cand_codes, luts, crude, thr, fast,
+                                       safe, slow=slow, pred=pred)
+        else:
+            # clamp into [topk, nc]: the slab is padded to >= topk columns
+            cap = min(max(refine_cap, topk), crude.shape[1])
+            masked = jnp.where(passed, crude, jnp.inf)
+            neg_s, surv = jax.lax.top_k(-masked, cap)    # slab positions
+            alive = jnp.isfinite(-neg_s)
+            surv_codes = jnp.take_along_axis(cand_codes, surv[:, :, None],
+                                             axis=1)     # (nq, cap, K)
+            full_surv = lut_sum(luts, _widen_slab(
+                surv_codes, luts.shape[1], code_bits))
+            ranked = jnp.where(alive, full_surv, jnp.inf)
+            with jax.named_scope("merge"):
+                neg, cpos = jax.lax.top_k(-ranked, topk)
+                pos = jnp.take_along_axis(surv, cpos, axis=1)
+                ids = jnp.take_along_axis(safe, pos, axis=1)
+                dist = -neg
+                if pred is not None:
+                    ids = mask_filtered_ids(ids, dist)
+        n_pass = jnp.sum(passed.astype(jnp.float32), axis=1)
     return ids, dist, n_cand, n_pass
 
 
@@ -471,28 +488,32 @@ def _ivf_crude_only_phase(qs, env, *, topk: int, n_probe: int,
     phase dropped, so the ranking is exactly the crude top-k the full
     path bootstraps its eq. 2 candidates from (same backend)."""
     luts = build_lut(qs, env["C"])
-    probes = coarse_probe(qs, env["centroids"], n_probe)
-    cand_ids, valid, cand_codes = gather_candidates(
-        probes, env["lists"], env["codes"], topk, env["list_codes"])
-    safe = jnp.where(valid, cand_ids, 0)
+    cand_ids, valid, cand_codes, safe = _probe_and_gather(qs, env, topk,
+                                                          n_probe)
     stage = CrudeStage(backend=backend, topk=topk, block_q=block_q,
                        block_n=block_n, interpret=interpret,
                        quantized=quantized, code_bits=code_bits)
     if backend == "pallas":
-        out = stage.slab(cand_codes, cand_ids, valid, luts, env["fast"])
-        pos_safe = jnp.where(jnp.isfinite(out.cand_vals), out.cand_idx, 0)
-        ids = jnp.take_along_axis(safe, pos_safe, axis=1)
+        with jax.named_scope("crude"):
+            out = stage.slab(cand_codes, cand_ids, valid, luts,
+                             env["fast"])
+        with jax.named_scope("merge"):
+            pos_safe = jnp.where(jnp.isfinite(out.cand_vals), out.cand_idx,
+                                 0)
+            ids = jnp.take_along_axis(safe, pos_safe, axis=1)
         n_cand = jnp.sum(valid.astype(jnp.float32), axis=1)
         return ids, out.cand_vals, n_cand, jnp.zeros_like(n_cand)
     pred = env["pred"] if has_filter else None
     if pred is not None:
         valid = valid & pred[safe]
-    out = stage.slab(cand_codes, cand_ids, valid, luts, env["fast"],
-                     need_slow=False)
-    neg_c, pos = jax.lax.top_k(-out.crude, topk)
-    ids = jnp.take_along_axis(safe, pos, axis=1)
-    if pred is not None:
-        ids = mask_filtered_ids(ids, -neg_c)
+    with jax.named_scope("crude"):
+        out = stage.slab(cand_codes, cand_ids, valid, luts, env["fast"],
+                         need_slow=False)
+    with jax.named_scope("merge"):
+        neg_c, pos = jax.lax.top_k(-out.crude, topk)
+        ids = jnp.take_along_axis(safe, pos, axis=1)
+        if pred is not None:
+            ids = mask_filtered_ids(ids, -neg_c)
     n_cand = jnp.sum(valid.astype(jnp.float32), axis=1)
     return ids, -neg_c, n_cand, jnp.zeros_like(n_cand)
 

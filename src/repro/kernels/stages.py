@@ -498,9 +498,10 @@ class RefineStage:
                 if self.code_bits == 4 else lut_sum(luts, codes, ~fast))
         passed = crude < thr[:, None]
         ranked = jnp.where(passed, crude + slow, jnp.inf)
-        neg, idx = jax.lax.top_k(-ranked, self.topk)
-        if pred is not None:
-            idx = mask_filtered_ids(idx, -neg)
+        with jax.named_scope("merge"):
+            neg, idx = jax.lax.top_k(-ranked, self.topk)
+            if pred is not None:
+                idx = mask_filtered_ids(idx, -neg)
         return idx, -neg, passed
 
     def slab(self, cand_codes, luts, crude, thr, fast, safe, *,
@@ -519,15 +520,17 @@ class RefineStage:
                 interpret=self.interpret, code_bits=self.code_bits)
             # merged positions are always real slab columns (the slab
             # is padded to >= topk); clip only guards take_along_axis
-            ids = jnp.take_along_axis(
-                safe, jnp.minimum(pos, safe.shape[1] - 1), axis=1)
+            with jax.named_scope("merge"):
+                ids = jnp.take_along_axis(
+                    safe, jnp.minimum(pos, safe.shape[1] - 1), axis=1)
             return ids, dist, crude < thr[:, None]
         passed = crude < thr[:, None]            # invalid -> inf -> False
         ranked = jnp.where(passed, crude + slow, jnp.inf)
-        neg, pos = jax.lax.top_k(-ranked, self.topk)
-        ids = jnp.take_along_axis(safe, pos, axis=1)
-        if pred is not None:
-            ids = mask_filtered_ids(ids, -neg)
+        with jax.named_scope("merge"):
+            neg, pos = jax.lax.top_k(-ranked, self.topk)
+            ids = jnp.take_along_axis(safe, pos, axis=1)
+            if pred is not None:
+                ids = mask_filtered_ids(ids, -neg)
         return ids, -neg, passed
 
 

@@ -43,6 +43,11 @@ class ServeError(RuntimeError):
 _rid_counter = itertools.count()
 
 
+def next_rid() -> int:
+    """A fresh request id, unique in the process."""
+    return next(_rid_counter)
+
+
 class PendingRequest:
     """One caller's in-flight request: its query rows, routing options,
     and the accumulator the loop fills as flush slices complete."""
@@ -51,8 +56,9 @@ class PendingRequest:
                  "future", "t_done", "_rows_done", "_parts", "_fills")
 
     def __init__(self, tenant: str, queries: np.ndarray,
-                 topk: Optional[int], budget, t_submit: float, future):
-        self.rid = next(_rid_counter)
+                 topk: Optional[int], budget, t_submit: float, future,
+                 rid: Optional[int] = None):
+        self.rid = next_rid() if rid is None else rid
         self.tenant = tenant
         self.queries = queries              # (nq, d) float32, host-side
         self.topk = topk

@@ -35,6 +35,7 @@ of the flushes that served it).
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from collections import deque
@@ -44,9 +45,10 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from repro.index.base import SearchResult
+from repro.obs import span
 from repro.resilience.budget import SearchBudget
 from repro.serve.coalescer import (Coalescer, FlushBatch, PendingRequest,
-                                   ServeError)
+                                   ServeError, next_rid)
 from repro.serve.tenants import Tenant
 
 _DEFAULT_TILE = 32
@@ -112,6 +114,7 @@ class ServingLoop:
         self._warmed: Dict[Tuple, bool] = {}
         self._stop = False
         self._thread: Optional[threading.Thread] = None
+        self._gc_open = None                 # the open repro.gc span
         self.stats: Dict[str, float] = {
             "requests": 0, "rows": 0, "batches": 0, "padded_rows": 0,
             "flush_full": 0, "flush_window": 0, "flush_drain": 0}
@@ -166,6 +169,7 @@ class ServingLoop:
         self._thread = threading.Thread(target=self._run,
                                         name="repro-serve-loop",
                                         daemon=True)
+        gc.callbacks.append(self._gc_span)
         self._thread.start()
         return self
 
@@ -192,6 +196,19 @@ class ServingLoop:
             self._cond.notify_all()
         self._thread.join()
         self._thread = None
+        gc.callbacks.remove(self._gc_span)
+
+    def _gc_span(self, phase, info):
+        """A ``repro.gc`` span over each full (generation 2) collection
+        while the loop runs (a ``gc.callbacks`` hook)."""
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._gc_open = span("gc", generation=2)
+            self._gc_open.__enter__()
+        elif self._gc_open is not None:
+            self._gc_open.__exit__(None, None, None)
+            self._gc_open = None
 
     def _drain_locked(self):
         for lane in self._lanes.values():
@@ -231,36 +248,41 @@ class ServingLoop:
         if q.ndim != 2:
             raise ServeError(
                 f"queries must be (nq, d) or (d,), got shape {q.shape}")
-        # embed BEFORE coalescing: per-request, so batching never
-        # changes the numbers a direct Searcher.search would produce
-        q = np.asarray(t.embed(q), dtype=np.float32)
-        if q.shape[1] != t.d:
-            raise ServeError(
-                f"tenant {t.name!r} serves d={t.d} queries, got "
-                f"d={q.shape[1]}")
-        budget = budget if budget is not None else t.budget
-        fut: Future = Future()
-        with self._cond:
-            if self._stop:
-                raise ServeError("ServingLoop is closed")
-            pending = sum(l.coal.pending_rows for l in self._lanes.values())
-            if pending + q.shape[0] > self._max_queue:
+        rid = next_rid()
+        with span("serve.submit", rid=rid, rows=q.shape[0]):
+            # embed BEFORE coalescing: per-request, so batching never
+            # changes the numbers a direct Searcher.search would produce
+            with span("serve.embed"):
+                q = np.asarray(t.embed(q), dtype=np.float32)
+            if q.shape[1] != t.d:
                 raise ServeError(
-                    f"serving queue full ({pending} rows pending, "
-                    f"max_queue={self._max_queue}); retry later or raise "
-                    "serve.max_queue")
-            now = self._clock()
-            req = PendingRequest(t.name, q, k, budget, now, fut)
-            lane_key = (t.name, k, budget)
-            lane = self._lanes.get(lane_key)
-            if lane is None:
-                lane = _Lane(t, k, budget, self._tile_of(t),
-                             self._window_s_of(t))
-                self._lanes[lane_key] = lane
-            self._ready.extend(lane.coal.submit(req, now))
-            self.stats["requests"] += 1
-            self.stats["rows"] += q.shape[0]
-            self._cond.notify()
+                    f"tenant {t.name!r} serves d={t.d} queries, got "
+                    f"d={q.shape[1]}")
+            budget = budget if budget is not None else t.budget
+            fut: Future = Future()
+            with span("serve.enqueue"), self._cond:
+                if self._stop:
+                    raise ServeError("ServingLoop is closed")
+                pending = sum(l.coal.pending_rows
+                              for l in self._lanes.values())
+                if pending + q.shape[0] > self._max_queue:
+                    raise ServeError(
+                        f"serving queue full ({pending} rows pending, "
+                        f"max_queue={self._max_queue}); retry later or "
+                        "raise serve.max_queue")
+                now = self._clock()
+                req = PendingRequest(t.name, q, k, budget, now, fut,
+                                     rid=rid)
+                lane_key = (t.name, k, budget)
+                lane = self._lanes.get(lane_key)
+                if lane is None:
+                    lane = _Lane(t, k, budget, self._tile_of(t),
+                                 self._window_s_of(t))
+                    self._lanes[lane_key] = lane
+                self._ready.extend(lane.coal.submit(req, now))
+                self.stats["requests"] += 1
+                self.stats["rows"] += q.shape[0]
+                self._cond.notify()
         return fut
 
     def search(self, queries, *, tenant: Optional[str] = None,
@@ -308,7 +330,8 @@ class ServingLoop:
                     deadlines = [d for d in deadlines if d is not None]
                     timeout = (max(min(deadlines) - now, 0.0)
                                if deadlines else None)
-                    self._cond.wait(timeout=timeout)
+                    with span("serve.idle"):
+                        self._cond.wait(timeout=timeout)
             self._execute(batch)
 
     def _execute(self, batch: FlushBatch):
@@ -318,42 +341,47 @@ class ServingLoop:
         lane_tenant = self.tenants[batch.slices[0].request.tenant]
         topk = batch.slices[0].request.topk
         budget = batch.slices[0].request.budget
-        t_flush = self._clock()
-        try:
-            q = batch.queries()
-            if batch.rows < batch.tile:         # pad to the compiled tile
-                pad = np.zeros((batch.tile - batch.rows, q.shape[1]),
-                               dtype=q.dtype)
-                q = np.concatenate([q, pad], axis=0)
-            res = lane_tenant.engine.search(q, topk, budget=budget)
-            ids = np.asarray(res.indices)
-            dists = np.asarray(res.distances)
-        except Exception as e:                  # noqa: BLE001
+        with span("serve.flush", reason=batch.reason, rows=batch.rows,
+                  tile=batch.tile):
+            t_flush = self._clock()
+            try:
+                with span("serve.pad"):
+                    q = batch.queries()
+                    if batch.rows < batch.tile:  # pad to the compiled tile
+                        pad = np.zeros((batch.tile - batch.rows,
+                                        q.shape[1]), dtype=q.dtype)
+                        q = np.concatenate([q, pad], axis=0)
+                res = lane_tenant.engine.search(q, topk, budget=budget)
+                with span("serve.copy"):
+                    ids = np.asarray(res.indices)
+                    dists = np.asarray(res.distances)
+            except Exception as e:              # noqa: BLE001
+                for s in batch.slices:
+                    if not s.request.future.done():
+                        s.request.future.set_exception(e)
+                return
+            self.stats["batches"] += 1
+            self.stats["padded_rows"] += batch.tile - batch.rows
+            self.stats[f"flush_{batch.reason}"] += 1
             for s in batch.slices:
-                if not s.request.future.done():
-                    s.request.future.set_exception(e)
-            return
-        self.stats["batches"] += 1
-        self.stats["padded_rows"] += batch.tile - batch.rows
-        self.stats[f"flush_{batch.reason}"] += 1
-        for s in batch.slices:
-            req = s.request
-            done = req.deliver(
-                s.req_start,
-                ids[s.batch_start:s.batch_start + s.rows],
-                dists[s.batch_start:s.batch_start + s.rows],
-                res, batch.fill)
-            if not done:
-                continue
-            r_ids, r_dists, last, fill = req.assemble()
-            meta = last.meta
-            if meta is not None:
-                meta = meta._replace(
-                    queue_ms=(t_flush - req.t_submit) * 1000.0,
-                    batch_fill=fill)
-            req.t_done = self._clock()
-            if not req.future.done():
-                req.future.set_result(SearchResult(
-                    indices=r_ids, distances=r_dists,
-                    avg_ops=last.avg_ops, pass_rate=last.pass_rate,
-                    meta=meta))
+                req = s.request
+                with span("serve.deliver", rid=req.rid):
+                    done = req.deliver(
+                        s.req_start,
+                        ids[s.batch_start:s.batch_start + s.rows],
+                        dists[s.batch_start:s.batch_start + s.rows],
+                        res, batch.fill)
+                    if not done:
+                        continue
+                    r_ids, r_dists, last, fill = req.assemble()
+                    meta = last.meta
+                    if meta is not None:
+                        meta = meta._replace(
+                            queue_ms=(t_flush - req.t_submit) * 1000.0,
+                            batch_fill=fill)
+                    req.t_done = self._clock()
+                    if not req.future.done():
+                        req.future.set_result(SearchResult(
+                            indices=r_ids, distances=r_dists,
+                            avg_ops=last.avg_ops, pass_rate=last.pass_rate,
+                            meta=meta))

@@ -1,0 +1,165 @@
+"""Host spans of the search and serving path (``repro.obs``) in the
+profiler's own trace: one ``Searcher.search`` and one ``ServingLoop``
+request traced on the CPU, the trace reduced with the benchmark's
+``bench.tracing.from_xplane``, and the names, the nesting and the
+request ids checked against docs/serving.md's table."""
+import gc
+import os
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from repro.api import ICQConfig, icq_session
+from repro.obs import span
+from repro.serve import ServingLoop, Tenant
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from bench import tracing  # noqa: E402
+
+# child -> the spans that may hold it, innermost, on the same line
+PARENTS = {
+    "repro.embed": {"repro.search"},
+    "repro.engine.search": {"repro.search", "repro.serve.flush"},
+    "repro.engine.pad": {"repro.engine.search"},
+    "repro.engine.dispatch": {"repro.engine.search"},
+    "repro.engine.wait": {"repro.engine.search"},
+    "repro.engine.assemble": {"repro.engine.search"},
+    "repro.serve.embed": {"repro.serve.submit"},
+    "repro.serve.enqueue": {"repro.serve.submit"},
+    "repro.serve.pad": {"repro.serve.flush"},
+    "repro.serve.copy": {"repro.serve.flush"},
+    "repro.serve.deliver": {"repro.serve.flush"},
+    "repro.search": {None},
+    "repro.serve.submit": {None},
+    "repro.serve.flush": {None},
+    "repro.serve.idle": {None},
+}
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One direct search (3 rows, padded to the loop's tile of 4) and
+    one served 1-row request, traced after a warm-up."""
+    rng = np.random.default_rng(0)
+    sess = icq_session(ICQConfig().with_overrides(
+        {"train.d": 16, "train.num_codebooks": 4,
+         "train.codebook_size": 16, "train.epochs": 1}))
+    sess.fit(rng.standard_normal((256, 32)).astype(np.float32),
+             key=jax.random.PRNGKey(0))
+    searcher = sess.index(rng.standard_normal((400, 32)).astype(np.float32))
+    q = rng.standard_normal((3, 32)).astype(np.float32)
+    out = str(tmp_path_factory.mktemp("trace"))
+    with ServingLoop(Tenant.from_searcher("s", searcher), window_ms=1.0,
+                     tile=4) as loop:
+        loop.warm()
+        loop.search(q[:1], timeout=60)
+        np.asarray(searcher.search(q).indices)
+        with jax.profiler.trace(out):
+            np.asarray(searcher.search(q).indices)
+            fut = loop.submit(q[:1])
+            fut.result(timeout=60)
+            gc.collect()
+    path = tracing.find_xplane(out)
+    return tracing.from_xplane(path), path
+
+
+def _lines(trace):
+    """{line: [(name, start, end), ...]} of the host lines holding
+    ``repro.`` spans."""
+    out = {}
+    for p, plane in enumerate(trace["planes"]):
+        for i, line in enumerate(plane["lines"]):
+            evs = [(n, s, s + d) for n, s, d in line["events"]
+                   if n.startswith("repro.")]
+            if evs:
+                out[(p, i)] = evs
+    return out
+
+
+def _parent(ev, evs):
+    """The innermost other span of the line that holds ``ev``."""
+    name, s, e = ev
+    holders = [o for o in evs if o is not ev and o[1] <= s and e <= o[2]
+               and (o[2] - o[1]) > (e - s)]
+    return min(holders, key=lambda o: o[2] - o[1])[0] if holders else None
+
+
+def test_every_span_of_the_table_appears_once_or_more(traced):
+    trace, _ = traced
+    names = {n for evs in _lines(trace).values() for n, _, _ in evs}
+    assert set(PARENTS) <= names
+    assert "repro.gc" in names
+
+
+def test_spans_nest_as_the_table_says(traced):
+    trace, _ = traced
+    seen = set()
+    for evs in _lines(trace).values():
+        for ev in evs:
+            if ev[0] in PARENTS:
+                assert _parent(ev, evs) in PARENTS[ev[0]], ev
+                seen.add((ev[0], _parent(ev, evs)))
+    # the engine runs under both callers
+    assert ("repro.engine.search", "repro.search") in seen
+    assert ("repro.engine.search", "repro.serve.flush") in seen
+
+
+def test_submit_and_worker_spans_sit_on_their_own_lines(traced):
+    trace, _ = traced
+    lines = _lines(trace)
+    where = {}
+    for k, evs in lines.items():
+        for n, _, _ in evs:
+            where.setdefault(n, set()).add(k)
+    worker = where["repro.serve.flush"]
+    assert len(worker) == 1
+    assert where["repro.serve.idle"] == worker
+    assert where["repro.serve.submit"].isdisjoint(worker)
+    assert where["repro.search"] == where["repro.serve.submit"]
+
+
+def test_a_request_id_links_its_submit_and_deliver_spans(traced):
+    _, path = traced
+    pd = jax.profiler.ProfileData.from_file(path)
+    rids = {"repro.serve.submit": [], "repro.serve.deliver": []}
+    attrs = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in rids:
+                    rids[e.name].append(dict(e.stats)["rid"])
+                if e.name in ("repro.serve.flush", "repro.engine.search"):
+                    attrs[e.name] = dict(e.stats)
+    assert len(rids["repro.serve.submit"]) == 1
+    assert rids["repro.serve.deliver"] == rids["repro.serve.submit"]
+    assert attrs["repro.serve.flush"]["rows"] == 1
+    assert attrs["repro.serve.flush"]["tile"] == 4
+    assert attrs["repro.engine.search"]["level"] == "full"
+
+
+def test_a_span_name_is_built_once():
+    with span("test.quiet", rows=1):
+        pass
+    from repro import obs
+    first = obs._NAMES["test.quiet"]
+    with span("test.quiet"):
+        pass
+    assert obs._NAMES["test.quiet"] is first
+
+
+def test_gc_spans_are_hooked_only_while_a_loop_runs():
+    class _Engine:
+        query_tile = None
+        index = None
+
+    loop = ServingLoop(Tenant(name="t", engine=_Engine()), tile=2)
+    assert loop._gc_span not in gc.callbacks
+    loop.start()
+    assert gc.callbacks.count(loop._gc_span) == 1
+    loop.close()
+    assert loop._gc_span not in gc.callbacks

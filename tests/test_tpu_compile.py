@@ -13,6 +13,7 @@ The topology is described inside a module-scoped fixture, never at
 import time: only one process may load the TPU library, and every test
 worker imports this file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,3 +97,51 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the names the benchmark's kernel metrics match in the device trace
+KERNEL_OP = re.compile(r"%((ivf_)?(crude|refine)_topk_pallas(\.\d+)?) = "
+                       r".*op_name=\"([^\"]*)\"")
+
+
+@pytest.mark.parametrize("kind", ["two-step", "ivf"])
+def test_search_kernels_keep_their_names_under_stage_scopes(one_chip, kind):
+    """The whole search compiled for the chip: the stage scopes reach
+    the kernels' ``op_name`` and leave their instruction names, which
+    the trace reports, as they were."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.core.icq import ICQStructure
+    from repro.index import make_index
+
+    n, kk, m, d = 4096, 8, 256, 128
+    rng = np.random.default_rng(0)
+    emb = rng.standard_normal((n, d)).astype(np.float32)
+    codes = rng.integers(0, m, (n, kk)).astype(np.uint8)
+    C = rng.standard_normal((kk, m, d)).astype(np.float32)
+    st = ICQStructure(xi=np.arange(d) < 64,
+                      fast_mask=np.arange(kk) < 2, sigma=np.float32(1.0))
+    opts = dict(emb_db=emb, n_lists=16, n_probe=4) if kind == "ivf" else {}
+    idx = make_index(kind, codes, C, st, topk=TOPK, backend="pallas",
+                     **opts)
+    idx = dataclasses.replace(idx, interpret=False)
+    fields = ["codes", "C", "structure"] + (
+        ["ivf", "list_codes"] if kind == "ivf" else [])
+
+    def search(q, *vals):
+        return dataclasses.replace(idx, **dict(zip(fields, vals))).search(q)
+
+    def shape(a):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                           sharding=one_chip), a)
+
+    args = [shape(np.zeros((NQ, d), np.float32))] + [
+        shape(getattr(idx, f)) for f in fields]
+    text = jax.jit(search).lower(*args).compile().as_text()
+    found = {m.group(3): m.group(5) for m in KERNEL_OP.finditer(text)}
+    assert set(found) == {"crude", "refine"}
+    for stage, op_name in found.items():
+        assert f"/{stage}/" in op_name
