@@ -117,6 +117,7 @@ class AnnEngine:
         else:
             self._view = self.index
         self._fns: Dict[Tuple, Any] = {}
+        self._cols: Dict[Tuple, Tuple[int, int]] = {}
         self._warmed = set()
 
     def _backend_eff(self) -> str:
@@ -174,6 +175,36 @@ class AnnEngine:
             if np_eff != int(idx.n_probe):
                 repl["n_probe"] = np_eff
         return dataclasses.replace(idx, **repl) if repl else idx
+
+    def _kernel_cols(self, level: str) -> Tuple[int, int]:
+        """``(crude_cols, refine_cols)``: the contraction width of the
+        fused crude and refine kernels at this rung, 0 where the rung
+        runs no such kernel (the jnp and sharded engines, the refine of
+        the crude rung).  Read off the LUT operands the search stages
+        build from this index's fast mask, which the engine's programs
+        hold as a constant, so it is what those kernels receive on the
+        backend the span's ``backend`` names.  Like ``backend``, it is
+        fixed as the call starts: a call that fails over to jnp reports
+        the Pallas widths, and the calls after it 0."""
+        be = self._backend_eff()
+        cols = self._cols.get((level, be))
+        if cols is None:
+            from repro.index.base import resolve_lut_dtype
+            from repro.kernels.stages import kernel_columns
+
+            idx = self.index
+            C = getattr(idx, "C", None)
+            cols = (0, 0)
+            if be == "pallas" and C is not None:
+                st = getattr(idx, "structure", None)
+                crude, refine = kernel_columns(
+                    None if st is None else st.fast_mask, *C.shape[:2],
+                    quantized=resolve_lut_dtype(
+                        getattr(idx, "lut_dtype", "f32")) == "int8",
+                    code_bits=getattr(idx, "code_bits", 8))
+                cols = (crude, 0 if level == "crude" else refine)
+            self._cols[(level, be)] = cols
+        return cols
 
     def _topk_default(self) -> int:
         return int(getattr(self.index, "topk", 50))
@@ -387,9 +418,11 @@ class AnnEngine:
         level = self._pick_level(budget)
         deadline = (budget.deadline_ms if budget.deadline_ms is not None
                     else self.resilience.deadline_ms)
+        crude_cols, refine_cols = self._kernel_cols(level)
         # the span covers what ``wall_ms`` times
         with span("engine.search", level=level,
-                  backend=self._backend_eff(), rows=len(queries)):
+                  backend=self._backend_eff(), rows=len(queries),
+                  crude_cols=crude_cols, refine_cols=refine_cols):
             t0 = time.perf_counter()
             key, result = self._serve_with_failover(level, k, budget,
                                                     queries, filter)

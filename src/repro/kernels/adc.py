@@ -20,22 +20,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def flat_onehot(codes, K: int, m: int, dtype):
-    """(blk_n, K) int codes -> (blk_n, K*m) one-hot over the flattened
-    LUT, with exactly K ones per row.
+def flat_onehot(codes, K: int, m: int, dtype, books=None):
+    """(blk_n, K) int codes -> (blk_n, len(books)*m) one-hot over the
+    flattened LUT of the codebooks ``books`` (a static tuple of ids,
+    default all K), one m-wide block per listed codebook in that order,
+    with exactly one 1 per block.  The ids need not be contiguous.
 
-    Built from a *single* iota compare against the flattened codes: column
-    j of the output matches iff codes[i, j // m] == j % m.  Peak
-    intermediate is O(blk_n * K * m) — the size of the result — instead of
-    the O(blk_n * K * K*m) boolean the K-way broadcast-then-sum
-    formulation materializes.
+    Each block is one compare of a code column against an m-wide iota,
+    so the peak intermediate is the size of the result,
+    O(blk_n * len(books) * m), and an unlisted codebook costs nothing.
     """
-    blk_n = codes.shape[0]
-    flat = codes + (jnp.arange(K, dtype=jnp.int32) * m)[None, :]   # (blk,K)
-    flat_rep = jnp.broadcast_to(flat[:, :, None],
-                                (blk_n, K, m)).reshape(blk_n, K * m)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (blk_n, K * m), 1)
-    return (flat_rep == iota).astype(dtype)
+    books = range(K) if books is None else books
+    iota = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], m), 1)
+    return jnp.concatenate(
+        [(codes[:, b:b + 1] == iota).astype(dtype) for b in books], axis=1)
 
 
 def _adc_kernel(codes_ref, lut_ref, out_ref, *, K: int, m: int):

@@ -76,72 +76,78 @@ def two_step(codes, lut, fast_mask, threshold, *, block_n: int = 512,
 def batched_crude_topk(codes, lut_flat, topk: int, *, block_q: int = 64,
                        block_n: int = 512, interpret=None,
                        want_crude: bool = True, lut_scale=None,
-                       lut_offset=None, code_bits: int = 8):
+                       lut_offset=None, code_bits: int = 8, books=None):
     """Batched phase 1: crude LUT sums for every (query, point) pair plus
     the in-kernel running top-k of crude distances.
 
-    codes (n, K) int (packed ok), lut_flat (nq, K*m) fast-masked
-    flattened tables — f32, or int8 with ``lut_scale``/``lut_offset``
-    (nq,) f32 (quantized-LUT mode; crude output is dequantized f32) ->
-    (crude (nq, n) | None, cand_vals (nq, topk), cand_idx (nq, topk));
-    ``want_crude=False`` skips the dense matrix.  ``code_bits=4`` is
-    fast-scan mode: nibble-packed codes (n, ceil(K/2)) uint8 against an
-    even-K-padded lut_flat (DESIGN.md §12).
+    codes (n, K) int (packed ok), lut_flat (nq, len(books)*m) tables of
+    the summed codebooks ``books`` (static ids, default all K) — f32,
+    or int8 with ``lut_scale``/``lut_offset`` (nq,) f32 (quantized-LUT
+    mode; crude output is dequantized f32) -> (crude (nq, n) | None,
+    cand_vals (nq, topk), cand_idx (nq, topk)); ``want_crude=False``
+    skips the dense matrix.  ``code_bits=4`` is fast-scan mode:
+    nibble-packed codes (n, ceil(K/2)) uint8 (DESIGN.md §12).
     """
     _check_faults("batched_crude_topk")
     it = _default_interpret() if interpret is None else interpret
     return crude_topk_pallas(codes, lut_flat, lut_scale, lut_offset,
                              topk=topk, block_q=block_q,
                              block_n=block_n, interpret=it,
-                             want_crude=want_crude, code_bits=code_bits)
+                             want_crude=want_crude, code_bits=code_bits,
+                             books=books)
 
 
 def batched_refine_topk(codes, lut_flat, crude, thresholds, topk: int, *,
                         block_q: int = 64, block_n: int = 512,
-                        interpret=None, code_bits: int = 8):
+                        interpret=None, code_bits: int = 8, books=None):
     """Batched phase 2: fused eq. 2 test + slow-codebook sum + top-k merge.
 
-    codes (n, K) int, lut_flat (nq, K*m) f32 (slow-masked), crude (nq, n),
-    thresholds (nq,) -> (dist (nq, topk), idx (nq, topk)).
+    codes (n, K) int, lut_flat (nq, len(books)*m) f32 slow tables of
+    the codebooks ``books`` (default all K), crude (nq, n), thresholds
+    (nq,) -> (dist (nq, topk), idx (nq, topk)).
     """
     _check_faults("batched_refine_topk")
     it = _default_interpret() if interpret is None else interpret
     return refine_topk_pallas(codes, lut_flat, crude, thresholds, topk=topk,
                               block_q=block_q, block_n=block_n, interpret=it,
-                              code_bits=code_bits)
+                              code_bits=code_bits, books=books)
 
 
 def ivf_crude_topk(cand_codes, cand_ids, lut_flat, topk: int, *,
                    block_q: int = 8, block_n: int = 128, interpret=None,
-                   lut_scale=None, lut_offset=None, code_bits: int = 8):
+                   lut_scale=None, lut_offset=None, code_bits: int = 8,
+                   books=None):
     """IVF phase 1 over the gathered candidate slab: crude LUT sums +
     in-kernel running top-k of crude distances (slab positions).
 
     cand_codes (nq, nc, K) int (packed ok), cand_ids (nq, nc) int32
-    global ids (-1 pad), lut_flat (nq, K*m) fast-masked tables — f32,
-    or int8 with ``lut_scale``/``lut_offset`` (nq,) f32 (quantized-LUT
-    mode; crude output is dequantized f32) -> (crude (nq, nc) with
-    invalid +inf, vals (nq, topk), pos (nq, topk)).
+    global ids (-1 pad), lut_flat (nq, len(books)*m) tables of the
+    summed codebooks ``books`` (default all K) — f32, or int8 with
+    ``lut_scale``/``lut_offset`` (nq,) f32 (quantized-LUT mode; crude
+    output is dequantized f32) -> (crude (nq, nc) with invalid +inf,
+    vals (nq, topk), pos (nq, topk)).
     """
     _check_faults("ivf_crude_topk")
     it = _default_interpret() if interpret is None else interpret
     return ivf_crude_topk_pallas(cand_codes, cand_ids, lut_flat, lut_scale,
                                  lut_offset, topk=topk,
                                  block_q=block_q, block_n=block_n,
-                                 interpret=it, code_bits=code_bits)
+                                 interpret=it, code_bits=code_bits,
+                                 books=books)
 
 
 def ivf_refine_topk(cand_codes, lut_flat, crude, thresholds, topk: int, *,
                     block_q: int = 8, block_n: int = 128, interpret=None,
-                    code_bits: int = 8):
+                    code_bits: int = 8, books=None):
     """IVF phase 2: fused eq. 2 test + slow-codebook sum + top-k merge
-    over the candidate slab -> (dist (nq, topk), pos (nq, topk))."""
+    over the candidate slab (``lut_flat`` covers the codebooks
+    ``books``, default all K) -> (dist (nq, topk), pos (nq, topk))."""
     _check_faults("ivf_refine_topk")
     it = _default_interpret() if interpret is None else interpret
     return ivf_refine_topk_pallas(cand_codes, lut_flat, crude, thresholds,
                                   topk=topk, block_q=block_q,
                                   block_n=block_n, interpret=it,
-                                  code_bits=code_bits)
+                                  code_bits=code_bits, books=books)
 
 
 def icm_encode(x, init_codes, C, *, iters: int = 3, block_n: int = 1024,

@@ -61,6 +61,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 I32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -180,21 +181,28 @@ def unpack_nibble_tile(packed):
     return jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1], -1)
 
 
-def resolve_kernel_code_bits(code_bits: int, Kc: int, Km: int):
+def resolve_kernel_code_bits(code_bits: int, Kc: int, Km: int,
+                             books=None):
     """Shared wrapper-side geometry: the stored code columns ``Kc``
     widen to ``K = 2 * Kc`` codebook columns under the nibble format
-    (``code_bits=4``); the flattened LUT width ``Km`` must then be an
-    even-K multiple (sentinel codebook included)."""
+    (``code_bits=4``).  ``books`` (static codebook ids, default all K)
+    are the codebooks the flattened LUT of width ``Km`` covers, in its
+    column order.  Returns ``(K, m, books)``."""
     if code_bits not in (8, 4):
         raise ValueError(f"unknown code_bits {code_bits!r}; "
                          f"expected one of (8, 4)")
     K = 2 * Kc if code_bits == 4 else Kc
-    if Km % K:
-        raise ValueError(
-            f"lut_flat width {Km} is not a multiple of K={K}"
-            + (" (pad odd-K tables with index.base.pad_luts_even)"
-               if code_bits == 4 else ""))
-    return K, Km // K
+    books = tuple(range(K)) if books is None else tuple(books)
+    if (not books or len(set(books)) != len(books)
+            or not all(0 <= b < K for b in books)):
+        raise ValueError(f"books {books} must be distinct codebook ids "
+                         f"in [0, {K})")
+    if Km % len(books):
+        hint = (" (pad odd-K tables with index.base.pad_luts_even)"
+                if code_bits == 4 else "")
+        raise ValueError(f"lut_flat width {Km} is not a multiple of "
+                         f"{len(books)} codebooks{hint}")
+    return K, Km // len(books), books
 
 
 def check_quantized_args(lut_flat, lut_scale, lut_offset) -> bool:
@@ -223,39 +231,100 @@ def widen_codes(codes, K: int, code_bits: int):
 
 # ---------------------------------------------------- kernel LUT operands ----
 
+def pass_books(fast, *, slow: bool = False):
+    """The codebook ids one search pass sums, as a static tuple: the
+    fast set for the crude pass, the rest for refine (``slow``), in
+    ascending order (an interleaved set need not be contiguous).
+
+    Read from a *concrete* mask: the search programs close over the
+    index state as trace constants, so its fast mask is concrete while
+    they trace.  A mask that arrives as a tracer gives None, and so
+    does a pass with no codebooks: the caller then keeps the masked
+    full-width operand."""
+    if fast is None or isinstance(fast, jax.core.Tracer):
+        return None
+    mask = np.asarray(fast).astype(bool)
+    books = tuple(int(b) for b in np.flatnonzero(~mask if slow else mask))
+    return books or None
+
+
+def kernel_columns(fast, K: int, m: int, *, quantized: bool = False,
+                   code_bits: int = 8):
+    """``(crude_cols, refine_cols)``: the width of the LUT operand each
+    fused search kernel receives for fast mask ``fast``, read off the
+    shapes ``crude_lut_operands`` and ``slow_lut_operand`` build for it
+    (an abstract evaluation: nothing runs).  ``fast`` None is one-step
+    ADC: every codebook in crude, no refine pass (0)."""
+    luts = jax.ShapeDtypeStruct((1, K, m), jnp.float32)
+    fast = None if fast is None else jnp.asarray(fast)
+    crude = jax.eval_shape(lambda t: crude_lut_operands(
+        t, fast, quantized=quantized, code_bits=code_bits)[0], luts)
+    if fast is None:
+        return crude.shape[1], 0
+    refine = jax.eval_shape(lambda t: slow_lut_operand(
+        t, fast, code_bits=code_bits)[0], luts)
+    return crude.shape[1], refine.shape[1]
+
+
+def _narrow(flat, m: int, books):
+    """Keep the listed codebooks' m-wide column blocks of a flattened
+    (nq, Kf*m) table, in the order of ``books``."""
+    nq = flat.shape[0]
+    return flat.reshape(nq, -1, m)[:, list(books)].reshape(nq, -1)
+
+
 def crude_lut_operands(luts, fast=None, *, quantized: bool,
                        code_bits: int = 8):
-    """The crude pass's flattened kernel operand triple ``(lut_flat,
-    lut_scale, lut_offset)`` from per-query tables ``luts`` ((nq, K, m)
-    f32) and the optional fast mask — the branch every Pallas search
-    path used to inline.  f32 mode masks the tables and returns
-    ``(flat, None, None)``; int8 mode calibrates the per-query affine
-    (``quantized_kernel_operands`` / even-K ``fastscan_kernel_operands``
-    under the nibble format)."""
+    """The crude pass's flattened kernel operands ``(lut_flat,
+    lut_scale, lut_offset, books)`` from per-query tables ``luts``
+    ((nq, K, m) f32) and the optional fast mask — the branch every
+    Pallas search path used to inline.  int8 mode calibrates the
+    per-query affine over the fast set (``quantized_kernel_operands`` /
+    even-K ``fastscan_kernel_operands`` under the nibble format); f32
+    mode returns ``(flat, None, None, books)``.
+
+    With a concrete fast mask the table is narrowed to its fast
+    codebooks, (nq, |K_fast|*m) in the order of ``books``, and the
+    kernel contracts over those columns only; the int8 scale and offset
+    are unchanged, since the dropped codebooks are zero in the int8
+    table.  Without a mask (one-step ADC) or with a traced one the
+    table keeps every codebook, the fast mask multiplied in, and
+    ``books`` is None."""
     from repro.index.base import (fastscan_kernel_operands, pad_luts_even,
                                   quantized_kernel_operands)
     nibble = code_bits == 4
+    nq, _, m = luts.shape
+    books = pass_books(fast)
     if quantized:
-        return (fastscan_kernel_operands(luts, fast) if nibble
-                else quantized_kernel_operands(luts, fast))
-    if fast is None:
-        lut = luts
-    else:
-        fast_f = fast.astype(luts.dtype)[None, :, None]
-        lut = luts * fast_f
+        flat, scale, offset = (fastscan_kernel_operands(luts, fast)
+                               if nibble
+                               else quantized_kernel_operands(luts, fast))
+        return (flat if books is None else _narrow(flat, m, books), scale,
+                offset, books)
+    if books is not None:
+        return _narrow(luts.reshape(nq, -1), m, books), None, None, books
+    lut = luts if fast is None else luts * fast.astype(
+        luts.dtype)[None, :, None]
     lut = pad_luts_even(lut) if nibble else lut
-    return lut.reshape(luts.shape[0], -1), None, None
+    return lut.reshape(nq, -1), None, None, None
 
 
 def slow_lut_operand(luts, fast, *, code_bits: int = 8):
-    """The refine pass's flattened slow-masked f32 tables (the refine
-    pass is never quantized — eq. 2's exact re-ranking)."""
+    """The refine pass's flattened f32 tables and their ``books`` (the
+    refine pass is never quantized — eq. 2's exact re-ranking): the
+    slow codebooks only, (nq, (K - |K_fast|)*m), for a concrete mask;
+    every codebook with the fast ones zeroed, and ``books`` None, for a
+    traced one (see ``crude_lut_operands``)."""
     from repro.index.base import pad_luts_even
+    nq, _, m = luts.shape
+    books = pass_books(fast, slow=True)
+    if books is not None:
+        return _narrow(luts.reshape(nq, -1), m, books), books
     fast_f = fast.astype(luts.dtype)[None, :, None]
     lut_slow = luts * (1.0 - fast_f)
     lut_slow = (pad_luts_even(lut_slow) if code_bits == 4
-                else lut_slow).reshape(luts.shape[0], -1)
-    return lut_slow
+                else lut_slow).reshape(nq, -1)
+    return lut_slow, None
 
 
 # -------------------------------------------------------- stage protocol ----
@@ -315,14 +384,14 @@ class CrudeStage:
         nibble = self.code_bits == 4
         if self.backend == "pallas":
             from repro.kernels import ops
-            lut_flat, scale, offset = crude_lut_operands(
+            lut_flat, scale, offset, books = crude_lut_operands(
                 luts, fast, quantized=self.quantized,
                 code_bits=self.code_bits)
             crude, vals, idx = ops.batched_crude_topk(
                 codes, lut_flat, self.topk, block_q=self.block_q,
                 block_n=self.block_n, interpret=self.interpret,
                 want_crude=self.want_crude, lut_scale=scale,
-                lut_offset=offset, code_bits=self.code_bits)
+                lut_offset=offset, code_bits=self.code_bits, books=books)
             return CrudeOut(crude, vals, idx)
         from repro.index.base import (lut_sum, nibble_lut_sum,
                                       quantize_lut)
@@ -348,14 +417,14 @@ class CrudeStage:
         validity through the +inf-masked dense crude output."""
         if self.backend == "pallas":
             from repro.kernels import ops
-            lut_flat, scale, offset = crude_lut_operands(
+            lut_flat, scale, offset, books = crude_lut_operands(
                 luts, fast, quantized=self.quantized,
                 code_bits=self.code_bits)
             crude, vals, pos = ops.ivf_crude_topk(
                 cand_codes, cand_ids, lut_flat, self.topk,
                 block_q=self.block_q, block_n=self.block_n,
                 interpret=self.interpret, lut_scale=scale,
-                lut_offset=offset, code_bits=self.code_bits)
+                lut_offset=offset, code_bits=self.code_bits, books=books)
             return CrudeOut(crude, vals, pos)
         from repro.index.ivf import _ivf_crude_scores
         crude, slow = _ivf_crude_scores(luts, cand_codes, valid, fast,
@@ -486,12 +555,13 @@ class RefineStage:
                                       nibble_lut_sum)
         if self.backend == "pallas":
             from repro.kernels import ops
-            lut_slow = slow_lut_operand(luts, fast,
-                                        code_bits=self.code_bits)
+            lut_slow, books = slow_lut_operand(luts, fast,
+                                               code_bits=self.code_bits)
             dist, idx = ops.batched_refine_topk(
                 codes, lut_slow, crude, thr, self.topk,
                 block_q=self.block_q, block_n=self.block_n,
-                interpret=self.interpret, code_bits=self.code_bits)
+                interpret=self.interpret, code_bits=self.code_bits,
+                books=books)
             return idx, dist, crude < thr[:, None]
         K = luts.shape[1]
         slow = (nibble_lut_sum(luts, codes, K, ~fast)
@@ -512,12 +582,13 @@ class RefineStage:
         from repro.index.base import mask_filtered_ids
         if self.backend == "pallas":
             from repro.kernels import ops
-            lut_slow = slow_lut_operand(luts, fast,
-                                        code_bits=self.code_bits)
+            lut_slow, books = slow_lut_operand(luts, fast,
+                                               code_bits=self.code_bits)
             dist, pos = ops.ivf_refine_topk(
                 cand_codes, lut_slow, crude, thr, self.topk,
                 block_q=self.block_q, block_n=self.block_n,
-                interpret=self.interpret, code_bits=self.code_bits)
+                interpret=self.interpret, code_bits=self.code_bits,
+                books=books)
             # merged positions are always real slab columns (the slab
             # is padded to >= topk); clip only guards take_along_axis
             with jax.named_scope("merge"):

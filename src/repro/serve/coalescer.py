@@ -116,6 +116,12 @@ class FlushBatch:
     def fill(self) -> float:
         return self.rows / self.tile
 
+    @property
+    def lane(self) -> tuple:
+        """The ``(tenant, topk, budget)`` lane every request in it shares."""
+        r = self.slices[0].request
+        return (r.tenant, r.topk, r.budget)
+
     def queries(self) -> np.ndarray:
         return np.concatenate(
             [s.request.queries[s.req_start:s.req_start + s.rows]

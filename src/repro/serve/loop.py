@@ -318,8 +318,15 @@ class ServingLoop:
             with self._cond:
                 while True:
                     now = self._clock()
-                    for lane in self._lanes.values():
-                        self._ready.extend(lane.coal.poll(now))
+                    # a lane forms a window batch only while none of its
+                    # flushes waits: its rows queued behind that flush
+                    # join its next one, so a backlog drains instead of
+                    # leaving one stale batch ahead of every later
+                    # request; every other lane keeps its window
+                    waiting = {b.lane for b in self._ready}
+                    for key, lane in self._lanes.items():
+                        if key not in waiting:
+                            self._ready.extend(lane.coal.poll(now))
                     if self._ready:
                         batch = self._ready.popleft()
                         break

@@ -129,3 +129,191 @@ def test_flash_vs_model_chunked_attention(key):
     b = chunked_attention(q, k, v, causal=True, chunk=64)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------- narrowed contractions ----
+# Each fused search kernel contracts only over the codebooks its pass
+# sums: the fast set in crude, the rest in refine.  The narrowed kernels
+# are held to the masked full-width ones (books=None) and to the jnp
+# gather sums.  Tables are quarter-integers, so every sum is exact in
+# any order and ties between rows are common.
+
+NARROW_FAST = {
+    "interleaved": lambda K: (1, 5),
+    "one_fast": lambda K: (K - 1,),
+    "one_slow": lambda K: tuple(b for b in range(K) if b != 2),
+}
+
+
+def _narrow_problem(key, K, m, n, nq, fast_ids):
+    from repro.kernels.stages import crude_lut_operands, slow_lut_operand
+    codes = jax.random.randint(key, (n, K), 0, m)
+    luts = 8.0 + jnp.round(4.0 * jax.random.normal(
+        jax.random.fold_in(key, 1), (nq, K, m))) / 4.0
+    fast = np.zeros(K, bool)
+    fast[list(fast_ids)] = True
+    return codes, luts, fast, crude_lut_operands, slow_lut_operand
+
+
+def _masked_operands(luts, fast, quantized, code_bits):
+    """Today's full-width operands: every codebook, the mask multiplied
+    in (the path a traced mask keeps)."""
+    from repro.index.base import (fastscan_kernel_operands, pad_luts_even,
+                                  quantized_kernel_operands)
+    nq = luts.shape[0]
+    if quantized:
+        return (fastscan_kernel_operands(luts, jnp.asarray(fast))
+                if code_bits == 4
+                else quantized_kernel_operands(luts, jnp.asarray(fast)))
+    lut = luts * jnp.asarray(fast, luts.dtype)[None, :, None]
+    lut = pad_luts_even(lut) if code_bits == 4 else lut
+    return lut.reshape(nq, -1), None, None
+
+
+def _masked_slow(luts, fast, code_bits):
+    return _masked_operands(luts, ~fast, False, code_bits)[0]
+
+
+def _stored(codes, K, code_bits):
+    return ops.pack_nibbles(codes, K) if code_bits == 4 else \
+        codes.astype(jnp.uint8)
+
+
+@pytest.mark.parametrize("code_bits,K,m", [(8, 8, 32), (4, 7, 16)])
+@pytest.mark.parametrize("fast_set", sorted(NARROW_FAST))
+@pytest.mark.parametrize("quantized", [False, True])
+def test_narrowed_crude_topk_matches_masked(key, code_bits, K, m, fast_set,
+                                            quantized):
+    from repro.index.base import lut_sum, quantize_lut
+    n, nq, topk = 300, 5, 8
+    codes, luts, fast, crude_ops, _ = _narrow_problem(
+        key, K, m, n, nq, NARROW_FAST[fast_set](K))
+    flat, scale, offset, books = crude_ops(luts, jnp.asarray(fast),
+                                           quantized=quantized,
+                                           code_bits=code_bits)
+    assert books == tuple(np.flatnonzero(fast))
+    assert flat.shape == (nq, len(books) * m)
+    stored = _stored(codes, K, code_bits)
+    opts = dict(block_q=2, block_n=128, interpret=True, code_bits=code_bits)
+    crude, vals, idx = ops.batched_crude_topk(
+        stored, flat, topk, lut_scale=scale, lut_offset=offset,
+        books=books, **opts)
+    crude_w, vals_w, idx_w = ops.batched_crude_topk(
+        stored, *_masked_operands(luts, fast, quantized, code_bits)[:1],
+        topk, lut_scale=scale, lut_offset=offset, **opts)
+    table = quantize_lut(luts, jnp.asarray(fast)) if quantized else luts
+    want = lut_sum(table, codes, jnp.asarray(fast))
+    neg, idx0 = jax.lax.top_k(-want, topk)
+    for c, v, i in ((crude, vals, idx), (crude_w, vals_w, idx_w)):
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(idx0))
+        np.testing.assert_allclose(np.asarray(c), np.asarray(want),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(v), np.asarray(-neg),
+                                   rtol=1e-5)
+
+
+@pytest.mark.parametrize("code_bits,K,m", [(8, 8, 32), (4, 7, 16)])
+@pytest.mark.parametrize("fast_set", sorted(NARROW_FAST))
+def test_narrowed_refine_topk_matches_masked(key, code_bits, K, m,
+                                             fast_set):
+    """The slow pass over the slow codebooks only; a harsh threshold
+    leaves some rows fewer survivors than topk (the +inf tail)."""
+    from repro.index.base import lut_sum
+    n, nq, topk = 300, 5, 8
+    codes, luts, fast, _, slow_op = _narrow_problem(
+        key, K, m, n, nq, NARROW_FAST[fast_set](K))
+    crude0 = lut_sum(luts, codes, jnp.asarray(fast))
+    thr = jnp.quantile(crude0, 0.02, axis=1)
+    lut_slow, books = slow_op(luts, jnp.asarray(fast), code_bits=code_bits)
+    assert books == tuple(np.flatnonzero(~fast))
+    assert lut_slow.shape == (nq, len(books) * m)
+    stored = _stored(codes, K, code_bits)
+    opts = dict(block_q=2, block_n=128, interpret=True, code_bits=code_bits)
+    dist, idx = ops.batched_refine_topk(stored, lut_slow, crude0, thr, topk,
+                                        books=books, **opts)
+    dist_w, idx_w = ops.batched_refine_topk(
+        stored, _masked_slow(luts, fast, code_bits), crude0, thr, topk,
+        **opts)
+    full0 = crude0 + lut_sum(luts, codes, jnp.asarray(~fast))
+    neg, idx0 = jax.lax.top_k(
+        -jnp.where(crude0 < thr[:, None], full0, jnp.inf), topk)
+    assert np.isinf(np.asarray(neg)).any()
+    for d, i in ((dist, idx), (dist_w, idx_w)):
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(idx0))
+        np.testing.assert_allclose(np.asarray(d), np.asarray(-neg),
+                                   rtol=1e-5)
+
+
+@pytest.mark.parametrize("code_bits,K,m", [(8, 8, 32), (4, 7, 16)])
+@pytest.mark.parametrize("fast_set", sorted(NARROW_FAST))
+def test_narrowed_ivf_kernels_match_masked(key, code_bits, K, m, fast_set):
+    """The IVF slab kernels, crude then refine, over a slab with invalid
+    (-1) candidates and a width off the tile grid."""
+    from repro.index.base import lut_sum
+    nc, nq, topk = 300, 5, 8
+    codes, luts, fast, crude_ops, slow_op = _narrow_problem(
+        key, K, m, nq * nc, nq, NARROW_FAST[fast_set](K))
+    slab = codes.reshape(nq, nc, K)
+    ids = jnp.where(jax.random.bernoulli(jax.random.fold_in(key, 2), 0.2,
+                                         (nq, nc)), -1,
+                    jnp.arange(nq * nc).reshape(nq, nc))
+    stored = _stored(slab, K, code_bits)
+    opts = dict(block_q=2, block_n=128, interpret=True, code_bits=code_bits)
+    flat, _, _, cbooks = crude_ops(luts, jnp.asarray(fast), quantized=False,
+                                   code_bits=code_bits)
+    lut_slow, sbooks = slow_op(luts, jnp.asarray(fast), code_bits=code_bits)
+    assert flat.shape == (nq, len(cbooks) * m)
+    assert lut_slow.shape == (nq, len(sbooks) * m)
+    crude0 = jnp.where(ids >= 0, lut_sum(luts, slab, jnp.asarray(fast)),
+                       jnp.inf)
+    thr = jnp.quantile(jnp.where(ids >= 0, crude0, 1e9), 0.03, axis=1)
+    full0 = crude0 + lut_sum(luts, slab, jnp.asarray(~fast))
+    neg_c, pos_c = jax.lax.top_k(-crude0, topk)
+    neg, pos = jax.lax.top_k(
+        -jnp.where(crude0 < thr[:, None], full0, jnp.inf), topk)
+    assert np.isinf(np.asarray(neg)).any()
+    for crude_lut, slow_lut, cb, sb in (
+            (flat, lut_slow, cbooks, sbooks),
+            (_masked_operands(luts, fast, False, code_bits)[0],
+             _masked_slow(luts, fast, code_bits), None, None)):
+        crude, vals, cpos = ops.ivf_crude_topk(stored, ids, crude_lut, topk,
+                                               books=cb, **opts)
+        np.testing.assert_array_equal(np.asarray(cpos), np.asarray(pos_c))
+        np.testing.assert_allclose(np.asarray(crude), np.asarray(crude0),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(vals), np.asarray(-neg_c),
+                                   rtol=1e-5)
+        dist, rpos = ops.ivf_refine_topk(stored, slow_lut, crude, thr, topk,
+                                         books=sb, **opts)
+        np.testing.assert_array_equal(np.asarray(rpos), np.asarray(pos))
+        np.testing.assert_allclose(np.asarray(dist), np.asarray(-neg),
+                                   rtol=1e-5)
+
+
+@pytest.mark.parametrize("books", [None, (0, 1, 2, 3), (3, 1), (2,)])
+def test_flat_onehot_has_one_block_per_listed_codebook(key, books):
+    from repro.kernels.adc import flat_onehot
+    n, K, m = 37, 4, 16
+    codes = jax.random.randint(key, (n, K), 0, m)
+    got = flat_onehot(codes, K, m, jnp.float32, books)
+    listed = range(K) if books is None else books
+    want = np.concatenate([np.eye(m)[np.asarray(codes[:, b])]
+                           for b in listed], axis=1)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("fast,quantized,code_bits,K,books", [
+    (None, False, 8, 8, (8, 0)),        # one-step ADC: no refine pass
+    ((1, 5), False, 8, 8, (2, 6)),
+    ((1, 5), True, 8, 8, (2, 6)),
+    (None, False, 4, 7, (8, 0)),        # odd K: the zero sentinel book
+    ((6,), True, 4, 7, (1, 6)),         # narrowed: the sentinel never
+])
+def test_kernel_columns_are_the_operand_widths(fast, quantized, code_bits,
+                                               K, books):
+    from repro.kernels.stages import kernel_columns
+    m = 16
+    mask = None if fast is None else np.isin(np.arange(K), fast)
+    assert kernel_columns(mask, K, m, quantized=quantized,
+                          code_bits=code_bits) == (books[0] * m,
+                                                   books[1] * m)

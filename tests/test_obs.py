@@ -54,7 +54,9 @@ def traced(tmp_path_factory):
     searcher = sess.index(rng.standard_normal((400, 32)).astype(np.float32))
     q = rng.standard_normal((3, 32)).astype(np.float32)
     out = str(tmp_path_factory.mktemp("trace"))
-    with ServingLoop(Tenant.from_searcher("s", searcher), window_ms=1.0,
+    # a window long enough that even a loaded worker waits it out (the
+    # `repro.serve.idle` span) inside the traced window
+    with ServingLoop(Tenant.from_searcher("s", searcher), window_ms=20.0,
                      tile=4) as loop:
         loop.warm()
         loop.search(q[:1], timeout=60)
@@ -140,6 +142,9 @@ def test_a_request_id_links_its_submit_and_deliver_spans(traced):
     assert attrs["repro.serve.flush"]["rows"] == 1
     assert attrs["repro.serve.flush"]["tile"] == 4
     assert attrs["repro.engine.search"]["level"] == "full"
+    # the jnp engine runs no fused kernel
+    assert attrs["repro.engine.search"]["crude_cols"] == 0
+    assert attrs["repro.engine.search"]["refine_cols"] == 0
 
 
 def test_a_span_name_is_built_once():
@@ -163,3 +168,59 @@ def test_gc_spans_are_hooked_only_while_a_loop_runs():
     assert gc.callbacks.count(loop._gc_span) == 1
     loop.close()
     assert loop._gc_span not in gc.callbacks
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("kind", ["two-step", "ivf"])
+def test_each_pass_contracts_over_its_own_codebooks(kind, tmp_path):
+    """The Pallas search hands the crude kernel a (nq, |K_fast|*m) table
+    and the refine kernel a (nq, (K - |K_fast|)*m) one, for an
+    interleaved fast set; the ``repro.engine.search`` span records both
+    widths."""
+    from repro.api.serving import AnnEngine
+    from repro.core.icq import ICQStructure
+    from repro.index import make_index
+
+    n, K, m, d, nq = 512, 8, 16, 16, 4
+    rng = np.random.default_rng(0)
+    emb = rng.standard_normal((n, d)).astype(np.float32)
+    codes = rng.integers(0, m, (n, K)).astype(np.uint8)
+    C = rng.standard_normal((K, m, d)).astype(np.float32)
+    st = ICQStructure(xi=np.arange(d) < 8,
+                      fast_mask=np.isin(np.arange(K), (1, 5)),
+                      sigma=np.float32(1.0))
+    opts = dict(emb_db=emb, n_lists=8, n_probe=2) if kind == "ivf" else {}
+    idx = make_index(kind, codes, C, st, topk=5, backend="pallas", **opts)
+    q = rng.standard_normal((nq, d)).astype(np.float32)
+
+    lut_arg = {"two-step": {"crude": 1, "refine": 1},
+               "ivf": {"crude": 2, "refine": 1}}[kind]
+    widths = {}
+    for eqn in _pallas_calls(jax.make_jaxpr(idx.search)(q).jaxpr):
+        kernel = eqn.params["jaxpr"].debug_info.func_src_info
+        stage = "crude" if "_crude_" in kernel else "refine"
+        widths[stage] = eqn.invars[lut_arg[stage]].aval.shape[1]
+    assert widths == {"crude": 2 * m, "refine": 6 * m}
+
+    engine = AnnEngine(idx)
+    engine.search(q)
+    with jax.profiler.trace(str(tmp_path)):
+        engine.search(q)
+    pd = jax.profiler.ProfileData.from_file(tracing.find_xplane(str(tmp_path)))
+    attrs = [dict(e.stats) for plane in pd.planes for line in plane.lines
+             for e in line.events if e.name == "repro.engine.search"]
+    assert len(attrs) == 1
+    assert attrs[0]["crude_cols"] == 2 * m
+    assert attrs[0]["refine_cols"] == 6 * m
+    assert attrs[0]["backend"] == "pallas"

@@ -347,3 +347,87 @@ class TestLoadgen:
         assert s["qps"] > 0 and s["rows_per_s"] >= s["qps"]
         assert 0 < s["mean_batch_fill"] <= 1.0
         assert s["mean_queue_ms"] >= 0
+
+
+class _GatedEngine:
+    """A stand-in engine that serves a flush only when the test releases
+    it, and reports which requests each flush carried (a query row's
+    first coordinate is its request number; pad rows are zero)."""
+
+    def __init__(self, d: int):
+        import queue
+        import types
+        self.index = types.SimpleNamespace(C=np.zeros((1, 1, d)))
+        self.query_tile = None
+        self.started = queue.Queue()
+        self.release = queue.Queue()
+
+    def search(self, q, k=None, *, budget=None):
+        from repro.index.base import SearchResult
+        q = np.asarray(q)
+        self.started.put(sorted(int(v) for v in q[:, 0] if v > 0))
+        self.release.get(timeout=30)
+        zeros = np.zeros((q.shape[0], 1))
+        return SearchResult(zeros.astype(np.int32),
+                            zeros.astype(np.float32), 0.0, 0.0)
+
+
+def test_a_backlog_left_by_a_stall_drains():
+    """Rows that arrive while a flush runs join the very next flush,
+    also after a stall left a full tile waiting.  A window batch formed
+    while another flush already waits would stay one flush stale for
+    good, adding a flush to every later request's wait."""
+    eng = _GatedEngine(d=2)
+    count = iter(range(1, 100))
+
+    def send(loop):
+        i = next(count)
+        loop.submit(np.array([[i, 0.0]], np.float32))
+        return i
+
+    with ServingLoop(Tenant(name="t", engine=eng), tile=2,
+                     window_ms=0.0) as loop:
+        first = send(loop)
+        assert eng.started.get(timeout=30) == [first]
+        # the stall: while flush 0 runs, a full tile and one row queue up
+        stalled = [send(loop) for _ in range(3)]
+        eng.release.put(None)
+        assert eng.started.get(timeout=30) == stalled[:2]
+        for _ in range(4):
+            new = send(loop)
+            eng.release.put(None)
+            assert new in eng.started.get(timeout=30)
+        for _ in range(3):
+            eng.release.put(None)
+
+
+def test_a_saturating_lane_leaves_other_lanes_their_window():
+    """While one lane queues a full tile during every flush, another
+    lane's window batch still forms and waits behind at most one flush:
+    a lane skips forming a window batch only while one of its own
+    flushes waits."""
+    eng = _GatedEngine(d=2)
+    count = iter(range(1, 100))
+
+    def send(loop, k=None):
+        i = next(count)
+        loop.submit(np.array([[i, 0.0]], np.float32), k=k)
+        return i
+
+    with ServingLoop(Tenant(name="t", engine=eng), tile=2,
+                     window_ms=0.0) as loop:
+        first = send(loop)
+        assert eng.started.get(timeout=30) == [first]
+        # while flush 0 runs: lane A (k=None) queues a full tile and
+        # lane B (k=5) one row whose window has passed
+        busy = [send(loop), send(loop)]
+        other = send(loop, k=5)
+        eng.release.put(None)
+        assert eng.started.get(timeout=30) == busy
+        # lane A queues another full tile during that flush, ahead of
+        # which lane B's window batch was already formed
+        send(loop), send(loop)
+        eng.release.put(None)
+        assert eng.started.get(timeout=30) == [other]
+        for _ in range(3):
+            eng.release.put(None)

@@ -27,6 +27,10 @@ N, K, M, D, NQ, TOPK = 2 ** 20, 8, 256, 128, 64, 10
 FS_K, FS_M = 16, 16                     # fast-scan: 16 nibble codebooks
 ENCODE_ROWS = 8192
 SLAB = 16 * 2048                        # IVF candidates: 16 probed lists
+# an interleaved fast set of 2 codebooks: the narrowed crude kernels
+# contract over 2 * M = 512 columns, the refine kernels over 6 * M = 1536
+FAST = (1, 5)
+SLOW = tuple(b for b in range(K) if b not in FAST)
 
 
 @pytest.fixture(scope="module")
@@ -76,6 +80,24 @@ def _kernel_call(name):
         return (lambda c, l, cr, t: bs.ivf_refine_topk_pallas(
             c, l, cr, t, topk=TOPK, interpret=False),
             [((NQ, SLAB, K), u8), lut, ((NQ, SLAB), f32), col])
+    if name == "crude_topk_narrowed":
+        return (lambda c, l: bs.crude_topk_pallas(
+            c, l, topk=TOPK, interpret=False, books=FAST),
+            [codes, ((NQ, len(FAST) * M), f32)])
+    if name == "refine_topk_narrowed":
+        return (lambda c, l, cr, t: bs.refine_topk_pallas(
+            c, l, cr, t, topk=TOPK, interpret=False, books=SLOW),
+            [codes, ((NQ, len(SLOW) * M), f32), ((NQ, N), f32), col])
+    if name == "ivf_crude_topk_narrowed":
+        return (lambda c, i, l: bs.ivf_crude_topk_pallas(
+            c, i, l, topk=TOPK, interpret=False, books=FAST),
+            [((NQ, SLAB, K), u8), ((NQ, SLAB), i32),
+             ((NQ, len(FAST) * M), f32)])
+    if name == "ivf_refine_topk_narrowed":
+        return (lambda c, l, cr, t: bs.ivf_refine_topk_pallas(
+            c, l, cr, t, topk=TOPK, interpret=False, books=SLOW),
+            [((NQ, SLAB, K), u8), ((NQ, len(SLOW) * M), f32),
+             ((NQ, SLAB), f32), col])
     if name == "fastscan_crude_topk":
         return (lambda c, l, s, o: bs.fastscan_crude_topk_pallas(
             c, l, s, o, topk=TOPK, interpret=False),
@@ -90,7 +112,9 @@ def _kernel_call(name):
 
 @pytest.mark.parametrize("name", [
     "crude_topk_f32", "crude_topk_int8", "refine_topk", "ivf_crude_topk",
-    "ivf_refine_topk", "fastscan_crude_topk", "icm_encode"])
+    "ivf_refine_topk", "fastscan_crude_topk", "icm_encode",
+    "crude_topk_narrowed", "refine_topk_narrowed", "ivf_crude_topk_narrowed",
+    "ivf_refine_topk_narrowed"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _kernel_call(name)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
@@ -104,11 +128,19 @@ KERNEL_OP = re.compile(r"%((ivf_)?(crude|refine)_topk_pallas(\.\d+)?) = "
                        r".*op_name=\"([^\"]*)\"")
 
 
-@pytest.mark.parametrize("kind", ["two-step", "ivf"])
-def test_search_kernels_keep_their_names_under_stage_scopes(one_chip, kind):
+@pytest.mark.parametrize("kind,traced", [
+    pytest.param("two-step", False, id="two-step"),
+    pytest.param("ivf", False, id="ivf"),
+    pytest.param("two-step", True, id="two-step-traced-structure"),
+    pytest.param("ivf", True, id="ivf-traced-structure")])
+def test_search_kernels_keep_their_names_under_stage_scopes(one_chip, kind,
+                                                            traced):
     """The whole search compiled for the chip: the stage scopes reach
     the kernels' ``op_name`` and leave their instruction names, which
-    the trace reports, as they were."""
+    the trace reports, as they were.  With the structure a constant, as
+    in the served programs, each kernel is narrowed to its pass's
+    codebooks; with it an argument (a traced fast mask) both kernels
+    take the masked full-width tables."""
     import dataclasses
 
     import numpy as np
@@ -122,12 +154,13 @@ def test_search_kernels_keep_their_names_under_stage_scopes(one_chip, kind):
     codes = rng.integers(0, m, (n, kk)).astype(np.uint8)
     C = rng.standard_normal((kk, m, d)).astype(np.float32)
     st = ICQStructure(xi=np.arange(d) < 64,
-                      fast_mask=np.arange(kk) < 2, sigma=np.float32(1.0))
+                      fast_mask=np.isin(np.arange(kk), FAST),
+                      sigma=np.float32(1.0))
     opts = dict(emb_db=emb, n_lists=16, n_probe=4) if kind == "ivf" else {}
     idx = make_index(kind, codes, C, st, topk=TOPK, backend="pallas",
                      **opts)
     idx = dataclasses.replace(idx, interpret=False)
-    fields = ["codes", "C", "structure"] + (
+    fields = ["codes", "C"] + (["structure"] if traced else []) + (
         ["ivf", "list_codes"] if kind == "ivf" else [])
 
     def search(q, *vals):
@@ -143,5 +176,11 @@ def test_search_kernels_keep_their_names_under_stage_scopes(one_chip, kind):
     text = jax.jit(search).lower(*args).compile().as_text()
     found = {m.group(3): m.group(5) for m in KERNEL_OP.finditer(text)}
     assert set(found) == {"crude", "refine"}
+    if traced:
+        assert f"f32[{NQ},{kk * m}]" in text
+    else:
+        # fast codebooks (1, 5) in crude, the other six in refine
+        assert (f"f32[{NQ},{2 * m}]" in text
+                and f"f32[{NQ},{6 * m}]" in text)
     for stage, op_name in found.items():
         assert f"/{stage}/" in op_name
