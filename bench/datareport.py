@@ -136,8 +136,8 @@ def main(argv=None) -> int:
     for over in grid:
         params = dict(cfg0["assumed"]["generator"], **over)
         params["clusters"] = max(1, round(params["clusters"] * args.scale))
-        rows = gen.make_rows(seed, n_learn + n_base + nq, sh["d"], params)
-        learn, base, queries = gen.split(rows, n_learn, n_base, nq)
+        learn, base, queries = gen.make_parts(seed, (n_learn, n_base, nq),
+                                              sh["d"], params)
         gt, d2 = truth.exact_neighbours(queries, base, 100)
         gt, d2 = np.asarray(gt), np.asarray(d2, np.float64)
         dist = np.sqrt(np.maximum(d2, 1e-12))

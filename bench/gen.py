@@ -35,8 +35,13 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.PRNGKey(s & 0x7FFFFFFF), s >> 31)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "d", "p"))
-def _rows(key, *, n: int, d: int, p: tuple):
+@functools.partial(jax.jit, static_argnames=("sizes", "d", "p"))
+def _parts(key, *, sizes: tuple, d: int, p: tuple):
+    """Consecutive ranges of the draw, ``sizes`` rows each, in one
+    program.  The draw's blocks are fixed (block i from ``fold_in(k_rows,
+    i)``); each range is written block by block into a buffer of its own,
+    a block that straddles an end of the range rolled into the buffer's
+    first or last ``BLOCK_ROWS`` rows and masked."""
     q = dict(p)
     nc, r = int(q["clusters"]), int(q["rank"])
     k_c, k_g, k_b, k_w, k_rows = jax.random.split(key, 5)
@@ -50,7 +55,6 @@ def _rows(key, *, n: int, d: int, p: tuple):
     within = q["within_std"] * jnp.arange(1, r + 1, dtype=jnp.float32) \
         ** -q["within_decay"]
     logits = q["size_sigma"] * jax.random.normal(k_w, (nc,), jnp.float32)
-    n_blocks = -(-n // BLOCK_ROWS)
 
     def block(i):
         kb = jax.random.fold_in(k_rows, i)
@@ -64,17 +68,40 @@ def _rows(key, *, n: int, d: int, p: tuple):
              + q["noise_std"] * e)
         return jnp.maximum(x, 0.0)
 
-    out = jax.lax.map(block, jnp.arange(n_blocks))
-    return out.reshape(-1, d)[:n]
+    j = jnp.arange(BLOCK_ROWS)
+
+    def part(start, n):
+        size = max(n, BLOCK_ROWS)
+
+        def put(i, out):
+            o = i * BLOCK_ROWS - start      # buffer row of the block's row 0
+            c = jnp.clip(o, 0, size - BLOCK_ROWS)
+            s = c - o                       # buffer row c + j: block row j + s
+            keep = (j + s >= 0) & (j + s < BLOCK_ROWS) & (c + j < n)
+            x = jnp.roll(block(i), -s, axis=0)
+            old = jax.lax.dynamic_slice_in_dim(out, c, BLOCK_ROWS)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.where(keep[:, None], x, old), c, 0)
+
+        out = jax.lax.fori_loop(start // BLOCK_ROWS,
+                                -(-(start + n) // BLOCK_ROWS), put,
+                                jnp.zeros((size, d), jnp.float32))
+        return out if size == n else out[:n]
+
+    starts = [sum(sizes[:i]) for i in range(len(sizes))]
+    return tuple(part(a, n) for a, n in zip(starts, sizes))
+
+
+def make_parts(seed: int, sizes, d: int, params: dict) -> list:
+    """Consecutive parts of the draw from ``seed`` (learn, base, query
+    pool), ``sizes`` rows each, as (n, d) float32 arrays on the default
+    device: the same bits as those ranges of one longer draw, made in one
+    program that never holds the whole draw beside its parts."""
+    p = tuple(sorted((k, float(v)) for k, v in params.items()))
+    return list(_parts(seed_key(seed), sizes=tuple(int(n) for n in sizes),
+                       d=int(d), p=p))
 
 
 def make_rows(seed: int, n: int, d: int, params: dict):
     """(n, d) float32 rows on the default device, from ``seed``."""
-    p = tuple(sorted((k, float(v)) for k, v in params.items()))
-    return _rows(seed_key(seed), n=int(n), d=int(d), p=p)
-
-
-def split(rows, n_learn: int, n_base: int, n_queries: int):
-    """learn / base / query pool from one block of independent draws."""
-    a, b = n_learn, n_learn + n_base
-    return rows[:a], rows[a:b], rows[b:b + n_queries]
+    return make_parts(seed, (n,), d, params)[0]

@@ -5,7 +5,10 @@ the device time of the search kernels, in percent.
 
 The work per batch is counted from the reference's answers to the
 window's sampled batches (rows scanned and rows passing eq. 2, per
-query and distinct per batch) and scaled to the traced batches."""
+query and distinct per batch) and scaled to the traced batches.  Where
+the search kernels run on several chips, each does a share of that work
+in the kernel time averaged over the chips: the least time is divided
+by the number of chips whose trace holds a search kernel."""
 import numpy as np
 
 from bench import peaks, workcount
@@ -40,4 +43,4 @@ def read(ctx):
         per_batch = per_batch + workcount.probe(nq=b, n_lists=n_lists, d=d)
     least, _ = workcount.least_seconds(per_batch,
                                        peaks.peaks_for(ctx["device_kind"]))
-    return 100.0 * least * len(calls) / t_kernels
+    return 100.0 * least * len(calls) / tr.op_chips(KERNELS) / t_kernels

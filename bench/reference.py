@@ -1,7 +1,9 @@
 """The plain reference: ICM encoding, the eq. 11 margin, the IVF lists and
 the two-step and IVF searches written out in ``jax.numpy`` from their
 definitions, with no kernels, tiling or batching tricks.  It imports
-nothing of the program.
+nothing of the program.  The ICM encoding and the flat search read the
+rows a block at a time (``bench/blocks.py``), so that a check over ten
+million rows fits one chip; the blocks change no result.
 
 It takes from the program's fit only what is learned by training the
 benchmark does not repeat: the codebooks ``C`` (K, m, d), the
@@ -48,7 +50,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import blocks
+
 HIGHEST = jax.lax.Precision.HIGHEST
+ROW_BLOCK = 131072          # rows per block of the flat search's two passes
 # one f32 add rounds by at most 2**-24 of its result (nearest) or 2**-23
 # (toward zero); a sum of d terms in any order lies within d times that
 # of the sum of absolute terms
@@ -63,13 +68,22 @@ def _sq_norms(C, precision):
 def icm_codes(x, C, *, iters: int, chunk: int = 8192, precision=HIGHEST):
     """ICM codes of rows ``x`` (n, d): the independent nearest codeword
     per codebook, then ``iters`` sweeps re-choosing codebook k with the
-    others fixed, k = 0..K-1.  Returns (n, K) int32."""
+    others fixed, k = 0..K-1.  Chunks of ``chunk`` rows are read in
+    place; only the last, short one is padded.  Returns (n, K) int32."""
     K = C.shape[0]
     sq = _sq_norms(C, HIGHEST)
     n, d = x.shape
-    xp = jnp.pad(x, ((0, (-n) % chunk), (0, 0)))
+    full, rem = divmod(n, chunk)
+    tail = jnp.pad(x[full * chunk:], ((0, (chunk - rem) % chunk), (0, 0)))
 
-    def block(xb):
+    def block(j):
+        if not full:
+            xb = tail
+        else:
+            xb = jax.lax.dynamic_slice_in_dim(
+                x, jnp.minimum(j, full - 1) * chunk, chunk)
+            if rem:
+                xb = jnp.where(j < full, xb, tail)
         scores = (-2.0 * jnp.einsum("nd,kmd->knm", xb, C,
                                     precision=precision) + sq[:, None, :])
         codes = [jnp.argmin(scores[k], axis=-1) for k in range(K)]
@@ -85,7 +99,7 @@ def icm_codes(x, C, *, iters: int, chunk: int = 8192, precision=HIGHEST):
                 recon = r + C[k][codes[k]]
         return jnp.stack(codes, axis=1).astype(jnp.int32)
 
-    out = jax.lax.map(block, xp.reshape(-1, chunk, d))
+    out = jax.lax.map(block, jnp.arange(full + (rem > 0)))
     return out.reshape(-1, K)[:n]
 
 
@@ -258,22 +272,48 @@ def _of_answers(T, codes, fast, answers):
     return jnp.where(valid, crude + slow, jnp.inf)
 
 
-@functools.partial(jax.jit, static_argnames=("topk",))
-def two_step_block(qs, codes, C, fast, sigma, answers, *, topk: int):
-    """Flat two-step over all rows for one query block.  Returns (ids,
-    dists, LUT range, passed per query, candidates per query, distance
-    of each given answer, whether each given answer is a row the search
-    can reach) and, for the block, the distinct rows scanned and
-    passed."""
+@functools.partial(jax.jit, static_argnames=("topk", "rows"))
+def two_step_block(qs, codes, C, fast, sigma, answers, *, topk: int,
+                   rows: int = ROW_BLOCK):
+    """Flat two-step over all rows for one query block, in two passes
+    over blocks of ``rows`` rows: the crude top-k and from it the eq. 2
+    threshold, then refine with a running top-k.  Returns (ids, dists,
+    LUT range, passed per query, candidates per query, distance of each
+    given answer, whether each given answer is a row the search can
+    reach) and, for the block, the distinct rows scanned and passed."""
     T = _luts(qs, C)
-    crude, slow = _sums(T, codes, fast)
-    ids, dist, passed = _two_step(crude, slow, sigma, topk)
     n = codes.shape[0]
+    rows = min(rows, n)
+    n_blocks = -(-n // rows)
+
+    def sums(j):
+        start, ids, new = blocks.row_block(j, n, rows)
+        crude, slow = _sums(
+            T, jax.lax.dynamic_slice_in_dim(codes, start, rows), fast)
+        return jnp.where(new, crude, jnp.inf), slow, ids
+
+    def crude_block(j):
+        crude, _, ids = sums(j)
+        return crude, ids, ()
+
+    (cand_vals, cand), _ = blocks.running_topk(crude_block, n_blocks, topk)
+    ok = jnp.isfinite(cand_vals)
+    full_cand = cand_vals + _sums(T, jnp.take(codes, cand, axis=0), fast)[1]
+    far = jnp.argmax(jnp.where(ok, full_cand, -jnp.inf), axis=1)
+    thr = jnp.take_along_axis(cand_vals, far[:, None], axis=1) + sigma
+
+    def refine_block(j):
+        crude, slow, ids = sums(j)
+        passed = crude < thr
+        return (jnp.where(passed, crude + slow, jnp.inf), ids,
+                (jnp.sum(passed, axis=1), jnp.sum(jnp.any(passed, axis=0))))
+
+    (dist, ids), (n_passed, rows_passed) = blocks.running_topk(
+        refine_block, n_blocks, topk)
     n_cand = jnp.full(qs.shape[:1], n, jnp.int32)
-    return (ids, dist, _lut_range(T), jnp.sum(passed, axis=1), n_cand,
+    return (ids, dist, _lut_range(T), n_passed, n_cand,
             _of_answers(T, codes, fast, answers),
-            (answers >= 0) & (answers < n), jnp.int32(n),
-            jnp.sum(jnp.any(passed, axis=0)))
+            (answers >= 0) & (answers < n), jnp.int32(n), rows_passed)
 
 
 @functools.partial(jax.jit, static_argnames=("topk", "n_probe", "rounded"))

@@ -10,9 +10,10 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 
   set-up    the configuration's data set made on the device, exact
             neighbours of the query pool, ``icq_session(cfg).fit`` on
-            the learn rows, ``session.index`` over the base rows, and
-            the traffic's shapes warmed by its driver (``setup_s``:
-            process start to here);
+            the learn rows, ``session.index`` over the base rows
+            (row-sharded over the cell's chips where it asks for more
+            than one), and the traffic's shapes warmed by its driver
+            (``setup_s``: process start to here);
   window    ``--seconds`` of the traffic, in an order drawn from
             ``--seed``, through the program's public entries
             (``Searcher.search`` or ``ServingLoop.submit``), under the
@@ -113,18 +114,19 @@ def make_data(cfg: dict):
     from bench import gen, truth
 
     sh = cfg["shapes"]
-    rows = gen.make_rows(cfg["assumed"]["data_seed"],
-                         sh["n_learn"] + sh["n_base"] + sh["n_queries"],
-                         sh["d"], cfg["assumed"]["generator"])
-    learn, base, pool = gen.split(rows, sh["n_learn"], sh["n_base"],
-                                  sh["n_queries"])
+    learn, base, pool = gen.make_parts(
+        cfg["assumed"]["data_seed"],
+        (sh["n_learn"], sh["n_base"], sh["n_queries"]), sh["d"],
+        cfg["assumed"]["generator"])
     gt, _ = truth.exact_neighbours(pool, base, sh["k"])
     return learn, base, np.asarray(pool), np.asarray(gt)
 
 
-def build(cfg: dict, learn, base, overrides=None):
+def build(cfg: dict, learn, base, overrides=None, devices=None):
     """Fit and index through the program's front door, keyed by the
-    configuration's ``data_seed``: one deployment, one index."""
+    configuration's ``data_seed``: one deployment, one index.  Over more
+    than one device the index is row-sharded on a mesh with one "data"
+    axis over them (``session.index(mesh=)``); over one, no mesh."""
     import jax
 
     from bench import gen
@@ -136,7 +138,11 @@ def build(cfg: dict, learn, base, overrides=None):
     key = gen.seed_key(cfg["assumed"]["data_seed"])
     session = icq_session(icq)
     session.fit(learn, key=jax.random.fold_in(key, 1))
-    return session.index(base, key=jax.random.fold_in(key, 2))
+    mesh = None
+    if devices is not None and len(devices) > 1:
+        mesh = jax.make_mesh((len(devices),), ("data",), devices=devices,
+                             axis_types=(jax.sharding.AxisType.Auto,))
+    return session.index(base, mesh=mesh, key=jax.random.fold_in(key, 2))
 
 
 # --------------------------------------------------------------- run ---
@@ -181,7 +187,8 @@ def prepare(cell: dict, *, require_tpu: bool = True, overrides=None,
     learn, base, pool, gt = make_data(cfg)
     learn_var = np.asarray(reference.learn_variance(learn))
     phase("data")
-    searcher = build(cfg, learn, base, overrides)
+    devices = devices[:chips]
+    searcher = build(cfg, learn, base, overrides, devices)
     phase("index")
     del learn
 
@@ -192,7 +199,7 @@ def prepare(cell: dict, *, require_tpu: bool = True, overrides=None,
         raise RuntimeError(f"the warm-up was served by {served!r}, not the "
                            f"configuration's {backend} kernels")
     phase("warm")
-    return {"cell": cell, "devices": devices[:chips], "clock": clock,
+    return {"cell": cell, "devices": devices, "clock": clock,
             "base": base, "learn_var": learn_var, "pool": pool, "gt": gt,
             "searcher": searcher, "kind": kind, "traffic": traffic,
             "backend": served, "setup_s": time.perf_counter() - T_START,

@@ -165,7 +165,9 @@ def test_benchmark_json_keeps_to_its_format():
             "name"] == c["name"]
     for w in spec["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+    four = sum(w["chips"] == 4 for w in spec["workloads"])
+    assert four <= max(1, len(spec["workloads"]) // 2)
     for m in spec["end_to_end"] + spec["per_layer"]:
         assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
     for m in spec["end_to_end"]:

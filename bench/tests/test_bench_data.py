@@ -43,10 +43,11 @@ def test_rows_are_clustered():
 
 
 def test_split_is_disjoint_and_ordered():
-    x = np.arange(10 * 2, dtype=np.float32).reshape(10, 2)
-    learn, base, q = gen.split(x, 3, 5, 2)
+    """Learn, base and query pool are consecutive ranges of one draw."""
+    x = np.asarray(gen.make_rows(4, 10, 16, PARAMS))
+    learn, base, q = gen.make_parts(4, (3, 5, 2), 16, PARAMS)
     assert learn.shape[0] == 3 and base.shape[0] == 5 and q.shape[0] == 2
-    np.testing.assert_array_equal(base[0], x[3])
+    np.testing.assert_array_equal(np.concatenate([learn, base, q]), x)
 
 
 def test_exact_neighbours_match_brute_force():

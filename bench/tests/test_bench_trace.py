@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from tinycell import ROOT
 
-from bench import tracing
+from bench import cells, tracing
 
 TRACE = os.path.join(ROOT, "bench", "tests", "data",
                      "trace_v5e_twostep.json")
@@ -88,3 +88,35 @@ def test_op_names_keep_the_instruction_name():
 def test_a_trace_without_the_window_span_raises():
     with pytest.raises(ValueError):
         tracing.Reduced({"planes": []})
+
+
+def _on_four_chips(trace):
+    """The recorded trace with its device plane on four chips."""
+    planes = [p for p in trace["planes"]
+              if not tracing.DEVICE_PLANE.match(p["name"])]
+    dev = [p for p in trace["planes"] if tracing.DEVICE_PLANE.match(p["name"])]
+    assert len(dev) == 1
+    return {"planes": planes + [dict(dev[0], name=f"/device:TPU:{i}")
+                                for i in range(4)]}
+
+
+def test_roofline_is_read_per_chip(trace):
+    """The batch's least time is shared by the chips that run the search
+    kernels: four chips that each take as long as one took for the whole
+    batch read a quarter of its share."""
+    ref = {"rows_scanned": np.array([1_000_000]),
+           "scanned": np.full(64, 1_000_000),
+           "passed": np.full(64, 950_000), "rows_passed": np.array([990_000])}
+    ctx = {"reference": ref, "calls": [None] * 3, "batch": 64,
+           "config": {"icq": {"train": {"num_codebooks": 8,
+                                        "codebook_size": 256}}},
+           "model": {"fast": np.isin(np.arange(8), (1, 5))},
+           "device_kind": "TPU v5 lite"}
+    read = cells.metric_reader("search_kernels_roofline")
+    one, four = tracing.Reduced(trace), tracing.Reduced(_on_four_chips(trace))
+    assert one.op_chips(KERNELS) == 1 and four.op_chips(KERNELS) == 4
+    assert four.op_chips((r"^no-such-op$",)) == 0
+    assert four.op_seconds(KERNELS) == pytest.approx(one.op_seconds(KERNELS))
+    share = read(dict(ctx, trace=one))
+    assert 0 < share < 100
+    assert read(dict(ctx, trace=four)) == pytest.approx(share / 4)

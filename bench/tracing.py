@@ -130,6 +130,13 @@ class Reduced:
                        if any(r.search(n) for r in rx))
         return tot / len(self.ops) / 1e9
 
+    def op_chips(self, patterns: Sequence[str]) -> int:
+        """How many devices ran an operation whose name matches any of
+        ``patterns``."""
+        rx = [re.compile(p) for p in patterns]
+        return sum(any(r.search(n) for n, _, _ in evs for r in rx)
+                   for evs in self.ops.values())
+
     def top_ops(self, n: int = 10) -> List[list]:
         """The ``n`` operation names with the most device time, summed
         over their events and averaged over devices."""
