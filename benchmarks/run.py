@@ -654,7 +654,7 @@ def train_bench(full: bool = False, *, out_path: str = "BENCH_train.json",
                              mode="icq",
                              sample_batch=(xtr[:4096], ytr[:4096]))
     step = make_train_step(cfg, state["embed_apply"], state["opt"], "icq",
-                           None)
+                           None, unit=state["unit"])
     nb = n // batch_size
 
     step_jit = jax.jit(step)

@@ -54,7 +54,7 @@ def main():
         k_init, cfg, embed_kind="linear", d_raw=64, num_classes=10,
         mode="icq", sample_batch=(xtr[:4096], ytr[:4096]))
     step = make_train_step(cfg, state["embed_apply"], state["opt"], "icq",
-                           None)
+                           None, unit=state["unit"])
     epoch_fn = compile_epoch(step, cfg.d)
     params, opt_state = state["params"], state["opt_state"]
     var_state = state["var_state"]
@@ -82,7 +82,7 @@ def main():
     # hold the tail out of the export, append it through the engine
     n_built = xtr.shape[0] - args.hold_out
     model = finalize(params, state["embed_apply"], var_state, cfg,
-                     xtr[:n_built], mode="icq")
+                     xtr[:n_built], unit=state["unit"], mode="icq")
     idx = make_index("two-step", model.codes, model.C, model.structure,
                      topk=50, backend="jnp")
     idx = idx.add(model.embed(xtr[n_built:]), icm_iters=cfg.icm_iters)

@@ -107,7 +107,11 @@ class AnnEngine:
     # ---------------------------------------------------------- plumbing --
     def _refresh(self):
         if self.mesh is not None:
-            view = self.index.shard(self.mesh)
+            src = self.index
+            if ("pallas" in self._blacklist
+                    and getattr(src, "backend", None) is not None):
+                src = dataclasses.replace(src, backend="jnp")
+            view = src.shard(self.mesh)
             # a refresh (engine.add) must not resurrect failed shards
             dead = (getattr(self._view, "dead_shards", frozenset())
                     if hasattr(self, "_view") else frozenset())
@@ -122,12 +126,15 @@ class AnnEngine:
 
     def _backend_eff(self) -> str:
         """The backend the engine currently dispatches to, after
-        failover blacklisting (sharded bodies are jnp-only)."""
+        failover blacklisting.  Under a mesh it is the one the sharded
+        view runs, its ``backend`` attribute: the source's on the
+        two-step index, "jnp" on the flat and IVF ones."""
         from repro.index.base import resolve_backend
 
         if self.mesh is not None:
-            return "jnp"
-        be = resolve_backend(getattr(self.index, "backend", "auto"))
+            be = getattr(self._view, "backend", "jnp")
+        else:
+            be = resolve_backend(getattr(self.index, "backend", "auto"))
         return "jnp" if be in self._blacklist else be
 
     def _levels(self) -> Tuple[str, ...]:
@@ -179,13 +186,15 @@ class AnnEngine:
     def _kernel_cols(self, level: str) -> Tuple[int, int]:
         """``(crude_cols, refine_cols)``: the contraction width of the
         fused crude and refine kernels at this rung, 0 where the rung
-        runs no such kernel (the jnp and sharded engines, the refine of
-        the crude rung).  Read off the LUT operands the search stages
-        build from this index's fast mask, which the engine's programs
-        hold as a constant, so it is what those kernels receive on the
-        backend the span's ``backend`` names.  Like ``backend``, it is
-        fixed as the call starts: a call that fails over to jnp reports
-        the Pallas widths, and the calls after it 0."""
+        runs no such kernel (the jnp engines and jnp shard bodies, the
+        refine of the crude rung); a sharded view on the kernels runs
+        the source index's widths on every shard.  Read off the LUT
+        operands the search stages build from this index's fast mask,
+        which the engine's programs hold as a constant, so it is what
+        those kernels receive on the backend the span's ``backend``
+        names.  Like ``backend``, it is fixed as the call starts: a call
+        that fails over to jnp reports the Pallas widths, and the calls
+        after it 0."""
         be = self._backend_eff()
         cols = self._cols.get((level, be))
         if cols is None:
@@ -387,6 +396,8 @@ class AnnEngine:
                     stacklevel=3)
                 self._blacklist.add("pallas")
                 self.stats["failovers"] += 1
+                if self.mesh is not None:
+                    self._refresh()          # re-shard onto the jnp body
                 self._fns.clear()
                 self._warmed.discard(key)
                 key, fn = self._level_fn(level, topk, budget, has_filter)
@@ -419,10 +430,12 @@ class AnnEngine:
         deadline = (budget.deadline_ms if budget.deadline_ms is not None
                     else self.resilience.deadline_ms)
         crude_cols, refine_cols = self._kernel_cols(level)
+        shards = 1 if self.mesh is None else int(self.mesh.shape["data"])
         # the span covers what ``wall_ms`` times
         with span("engine.search", level=level,
                   backend=self._backend_eff(), rows=len(queries),
-                  crude_cols=crude_cols, refine_cols=refine_cols):
+                  crude_cols=crude_cols, refine_cols=refine_cols,
+                  shards=shards):
             t0 = time.perf_counter()
             key, result = self._serve_with_failover(level, k, budget,
                                                     queries, filter)

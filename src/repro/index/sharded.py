@@ -18,13 +18,21 @@ Index state (codebooks, centroids, the fast mask and sigma, the
 alive-shard mask) enters every ``shard_map`` body as an operand with a
 replicated spec, never as a closure: a region may not close over arrays
 placed on a mesh, and passing them makes the wrappers work on any mesh a
-caller builds (``jax.make_mesh``'s Explicit axes included).
+caller builds (``jax.make_mesh``'s Explicit axes included).  The one
+exception is the fused-kernel body's fast mask, a host constant as in
+the one-chip programs, which each kernel's codebook narrowing reads.
 
-The shard_map bodies are jnp-only: the ``backend`` / ``interpret`` /
-tile options of the source index apply to its single-device engines and
-are intentionally not consulted here (fused-kernel sharded serving is a
-TPU bring-up item; the dispatch makes it a local change).  Likewise
-``pipeline`` (DESIGN.md §13): the sharded clones always serve
+Backends: ``ShardedTwoStep`` follows the source index's resolved
+``backend`` (its ``backend`` attribute).  On "pallas" each shard runs
+the one-chip engine's fused crude and refine kernels
+(``kernels/stages.py``) over its own rows, with the source's
+``block_q`` / ``block_n`` / ``interpret``; the merge above is
+unchanged, and ids stay bitwise-identical to the single-device Pallas
+engine.  A filtered search keeps the jnp body (the kernels cannot mask
+rows by predicate).  ``ShardedFlatADC`` and ``ShardedIVFTwoStep`` run
+jnp bodies only and say so (``backend = "jnp"``).  Both cross-chip
+merges of every body sit under ``jax.named_scope("shard_merge")``.
+Likewise ``pipeline`` (DESIGN.md §13): the sharded clones always serve
 ``pipeline="off"`` — the shard_map body is one fused SPMD program per
 batch, so there is no host-level crude/refine boundary to overlap;
 sharding a pipelined index yields a working non-pipelined clone.
@@ -68,7 +76,8 @@ from repro.distributed.sharding import shard_map_compat
 from repro.index import ivf as ivf_mod
 from repro.index.base import (SearchResult, as_filter, build_lut,
                               lut_sum, mask_filtered_ids, quantize_lut,
-                              resolve_code_bits, resolve_lut_dtype)
+                              resolve_backend, resolve_code_bits,
+                              resolve_lut_dtype)
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -119,14 +128,19 @@ def _data_size(mesh) -> int:
     return mesh.shape["data"]
 
 
-def _bind_state(mesh, body, state, in_specs, out_specs):
-    """Jit ``body(qs, *state, *arrays)`` under ``shard_map`` with the
-    replicated ``state`` operands bound: the returned callable takes
-    ``(qs, *arrays)`` like the search entry points."""
-    state = tuple(_put(mesh, x, P()) for x in state)
-    fn = jax.jit(shard_map_compat(
-        body, mesh, in_specs=(P(),) + (P(),) * len(state) + in_specs,
+def _program(mesh, body, n_state: int, in_specs, out_specs):
+    """Jit ``body(qs, *state, *arrays)`` under ``shard_map``: the query
+    batch and ``n_state`` state operands replicated, ``arrays`` laid out
+    by ``in_specs``."""
+    return jax.jit(shard_map_compat(
+        body, mesh, in_specs=(P(),) * (1 + n_state) + in_specs,
         out_specs=out_specs))
+
+
+def _bind(mesh, fn, state):
+    """``fn`` with the replicated ``state`` operands bound: the returned
+    callable takes ``(qs, *arrays)`` like the search entry points."""
+    state = tuple(_put(mesh, x, P()) for x in state)
     return lambda qs, *arrays: fn(qs, *state, *arrays)
 
 
@@ -224,6 +238,8 @@ class ShardedFlatADC(_DeadShardMixin):
         self.dead_shards = frozenset()
         self._fns = {}
 
+    backend = "jnp"                     # the shard body is jnp-only
+
     def _alive_rows(self) -> int:
         # shard s owns real rows [s*ns, min((s+1)*ns, n))
         return sum(max(0, min((s + 1) * self.ns, self.n) - s * self.ns)
@@ -254,12 +270,14 @@ class ShardedFlatADC(_DeadShardMixin):
                 keep = keep & rest[0][None, :]
             dist = jnp.where(keep, _sanitize(dist), jnp.inf)
             neg, li = jax.lax.top_k(-dist, k_loc)
-            mv, mg = _gather_sorted((-neg, jnp.take(gids, li)), "data")
+            with jax.named_scope("shard_merge"):
+                mv, mg = _gather_sorted((-neg, jnp.take(gids, li)), "data")
             return mg[:, :topk], mv[:, :topk]
 
         specs = (P("data"),) + ((P("data"),) if has_filter else ())
-        fn = _bind_state(self.mesh, body, (self.C, self._alive_arr()),
-                         specs, (P(), P()))
+        state = (self.C, self._alive_arr())
+        fn = _bind(self.mesh, _program(self.mesh, body, len(state), specs,
+                                       (P(), P())), state)
         self._fns[key] = fn
         return fn
 
@@ -287,6 +305,173 @@ class ShardedFlatADC(_DeadShardMixin):
 
 # ------------------------------------------------------------- two-step ----
 
+def _two_step_jnp_body(*, n, ns, topk, K, quantized, code_bits,
+                       has_filter):
+    """The jnp shard body: dense per-shard LUT sums over (nq, ns).
+    Operands ``(qs, C, fast, sigma, alive, codes_shard[, pred])``."""
+    k_loc = min(topk, ns)
+
+    def body(qs, C, fast, sigma, alive, codes_shard, *rest):
+        si = jax.lax.axis_index("data")
+        off = si * ns
+        if code_bits == 4:      # nibble slab: unpack once per shard
+            codes_shard = unpack_nibbles(codes_shard, K)
+        luts = build_lut(qs, C)
+        crude_lut = quantize_lut(luts, fast) if quantized else luts
+        crude = lut_sum(crude_lut, codes_shard, fast)      # (nq, ns)
+        gids = off + jnp.arange(ns, dtype=jnp.int32)
+        keep = (gids[None, :] < n) & alive[si]
+        if has_filter:
+            # filtered rows: crude +inf, so they can't bootstrap the
+            # eq. 2 threshold, can't pass it, and rank dead last —
+            # same exclusion semantics as the single-device engine
+            keep = keep & rest[0][None, :]
+        crude = jnp.where(keep, _sanitize(crude), jnp.inf)
+
+        # phase 1: local crude top-k + local full distances, merged
+        # globally before the threshold bootstrap (quantized mode
+        # mirrors the single-device decomposition: quantized crude
+        # + exact slow)
+        neg_c, li = jax.lax.top_k(-crude, k_loc)
+        cand_codes = jnp.take(codes_shard, li, axis=0)
+        if quantized:
+            full_cand = -neg_c + lut_sum(luts, cand_codes, ~fast)
+        else:
+            full_cand = lut_sum(luts, cand_codes)          # (nq, k_loc)
+        with jax.named_scope("shard_merge"):
+            sv, _, sf = _gather_sorted(
+                (-neg_c, jnp.take(gids, li), full_cand), "data")
+        thr = _merged_threshold(sv[:, :topk], sf[:, :topk], sigma)
+
+        # phase 2: prune against the global threshold, local refine
+        # top-k, merge by (full distance, global id)
+        passed = crude < thr[:, None]
+        slow = lut_sum(luts, codes_shard, ~fast)
+        ranked = jnp.where(passed, crude + slow, jnp.inf)
+        neg, li2 = jax.lax.top_k(-ranked, k_loc)
+        with jax.named_scope("shard_merge"):
+            mv, mg = _gather_sorted((-neg, jnp.take(gids, li2)), "data")
+            pf = jax.lax.psum(
+                jnp.sum(passed.astype(jnp.float32), axis=1), "data") / n
+        return mg[:, :topk], mv[:, :topk], pf
+
+    return body
+
+
+def _two_step_pallas_body(*, n, ns, D, topk, fast, quantized, code_bits,
+                          block_q, block_n, interpret):
+    """The fused-kernel shard body: each shard runs the one-chip
+    programs' crude and refine kernels (``kernels/stages.py``) on its
+    ``ns`` rows.  Operands as the jnp body's, ``(qs, C, fast_op, sigma,
+    alive, codes_shard)``.
+
+    The kernels read ``fast``, the host fast mask, a constant of the
+    program as in the one-chip engine, so each contracts over its
+    pass's codebooks only; ``fast_op``, the same mask as an operand, is
+    unread.  Kernel ids are shard-local; the shard's row offset is added
+    here, outside the kernels.  Rows past ``n`` (the last shards' pad to
+    ``D * ns``) and every row of a dead shard rank (+inf, id_max): the
+    crude kernel returns ``D * ns - n`` extra candidates so that pad
+    rows cannot push a real one out of a shard's top-k, a dead shard
+    prunes against -inf, and neither reaches the threshold bootstrap."""
+    from repro.kernels.stages import CrudeStage, RefineStage, ThresholdStage
+
+    fast = jnp.asarray(fast)
+    n_pad = D * ns - n
+    common = dict(block_q=block_q, block_n=block_n, interpret=interpret,
+                  code_bits=code_bits)
+    crude_st = CrudeStage(backend="pallas", topk=topk + n_pad,
+                          quantized=quantized, **common)
+    thr_st = ThresholdStage(topk=topk, quantized=quantized,
+                            code_bits=code_bits)
+    refine_st = RefineStage(backend="pallas", topk=topk, **common)
+
+    def global_ids(local, off, rows):
+        real = (local < rows) & (local >= 0)
+        return real, jnp.where(real, off + local, _I32_MAX)
+
+    def body(qs, C, fast_op, sigma, alive, codes_shard):
+        si = jax.lax.axis_index("data")
+        off = si * ns
+        rows = jnp.where(alive[si], jnp.clip(n - off, 0, ns), 0)
+        luts = build_lut(qs, C)
+        with jax.named_scope("crude"):
+            out = crude_st(codes_shard, luts, fast)
+
+        # phase 1: the shard's crude top-k with full distances, merged
+        # across shards before the eq. 2 bootstrap
+        with jax.named_scope("threshold"):
+            real, gid = global_ids(out.cand_idx, off, rows)
+            full = thr_st.candidate_full(
+                luts, codes_shard, out.cand_vals,
+                jnp.where(real, out.cand_idx, 0), fast)
+            vals = jnp.where(real, _sanitize(out.cand_vals), jnp.inf)
+        with jax.named_scope("shard_merge"):
+            sv, _, sf = _gather_sorted((vals, gid, full), "data")
+        with jax.named_scope("threshold"):
+            thr = _merged_threshold(sv[:, :topk], sf[:, :topk], sigma)
+            thr = jnp.where(alive[si], thr, -jnp.inf)
+
+        # phase 2: the refine kernel against the global threshold, its
+        # local top-k merged by (full distance, global id)
+        crude = out.crude
+        if n_pad:
+            crude = jnp.where(
+                jnp.arange(ns, dtype=jnp.int32)[None, :] < rows, crude,
+                jnp.inf)
+        with jax.named_scope("refine"):
+            li, dist, passed = refine_st(codes_shard, luts, crude, thr,
+                                         fast)
+            real, gid = global_ids(li, off, rows)
+            dist = jnp.where(real, _sanitize(dist), jnp.inf)
+        with jax.named_scope("shard_merge"):
+            mv, mg = _gather_sorted((dist, gid), "data")
+            pf = jax.lax.psum(
+                jnp.sum(passed.astype(jnp.float32), axis=1), "data") / n
+        return mg[:, :topk], mv[:, :topk], pf
+
+    return body
+
+
+def _merged_threshold(sv, sf, sigma):
+    """eq. 2's ``t + sigma`` from the merged crude top-k ``sv`` (sorted
+    by (crude, id)) and its full distances ``sf``.  +inf crude slots
+    (dead shards, tiny dbs) carry garbage full distances and are kept
+    out of the far-element argmax (a no-op when the merged top-k is
+    fully populated)."""
+    far = jnp.argmax(jnp.where(jnp.isfinite(sv), sf, -jnp.inf), axis=1)
+    t = jnp.take_along_axis(sv, far[:, None], axis=1)[:, 0]
+    return t + sigma
+
+
+def two_step_program(mesh, *, n: int, topk: int, K: int, fast,
+                     backend: str, quantized: bool = False,
+                     code_bits: int = 8, has_filter: bool = False,
+                     block_q: int = 64, block_n: int = 512,
+                     interpret=None):
+    """The jitted sharded two-step search over ``mesh``'s "data" axis,
+    with its state unbound: ``fn(qs, C, fast, sigma, alive, codes[,
+    pred]) -> (ids, dist, passed_frac (nq,))``, ``passed_frac`` counted
+    over the ``n`` real rows.  The body is the fused kernels' on
+    ``backend="pallas"`` without a filter and the jnp one otherwise;
+    both take the same operands.  ``fast`` is the host fast mask.
+    ``ShardedTwoStep`` binds the state; a compile test lowers the
+    program from shapes alone."""
+    D = _data_size(mesh)
+    ns = -(-n // D)
+    if backend == "pallas" and not has_filter:
+        body = _two_step_pallas_body(
+            n=n, ns=ns, D=D, topk=topk, fast=np.asarray(fast, bool),
+            quantized=quantized, code_bits=code_bits, block_q=block_q,
+            block_n=block_n, interpret=interpret)
+    else:
+        body = _two_step_jnp_body(n=n, ns=ns, topk=topk, K=K,
+                                  quantized=quantized, code_bits=code_bits,
+                                  has_filter=has_filter)
+    specs = (P("data"),) + ((P("data"),) if has_filter else ())
+    return _program(mesh, body, 4, specs, (P(), P(), P()))
+
+
 class ShardedTwoStep(_DeadShardMixin):
     """Row-sharded ICQ two-step.  The eq. 2 threshold is bootstrapped
     from the *merged* global crude top-k (each shard refines its local
@@ -294,10 +479,13 @@ class ShardedTwoStep(_DeadShardMixin):
     every shard prunes against the exact single-device threshold.
 
     Construction (`TwoStep.shard(mesh)`): codes rows zero-padded to a
-    multiple of the shard count, laid out P("data"); pad rows mask to
-    +inf before every local top-k.  ``lut_dtype="int8"`` quantizes the
-    crude pass per shard with the query-global affine (module
-    docstring); the slow/full tables stay f32."""
+    multiple of the shard count, laid out P("data"); pad rows rank last
+    in every local top-k.  ``backend`` is the source index's, resolved:
+    "pallas" runs the fused crude and refine kernels on each shard (a
+    filtered search keeps the jnp body), "jnp" the dense jnp body.
+    ``lut_dtype="int8"`` quantizes the crude pass per shard with the
+    query-global affine (module docstring); the slow/full tables stay
+    f32."""
 
     def __init__(self, base, mesh):
         self.mesh = mesh
@@ -308,6 +496,10 @@ class ShardedTwoStep(_DeadShardMixin):
         self.n = n
         self.ns = -(-n // D)
         self.topk = base.topk
+        self.backend = resolve_backend(getattr(base, "backend", "auto"))
+        self.block_q = getattr(base, "block_q", 64)
+        self.block_n = getattr(base, "block_n", 512)
+        self.interpret = getattr(base, "interpret", None)
         self.lut_dtype = resolve_lut_dtype(getattr(base, "lut_dtype", "f32"))
         self.code_bits = resolve_code_bits(getattr(base, "code_bits", 8))
         self.codes = _put(mesh, _pad_rows(base.codes, D * self.ns),
@@ -324,75 +516,23 @@ class ShardedTwoStep(_DeadShardMixin):
         key = (topk, self._dead_key(), has_filter)
         if key in self._fns:
             return self._fns[key]
-        n, ns = self.n, self.ns
-        K = self.C.shape[0]
-        k_loc = min(topk, ns)
-        quantized = self.lut_dtype == "int8"
-        code_bits = self.code_bits
-
-        def body(qs, C, fast, sigma, alive, codes_shard, *rest):
-            si = jax.lax.axis_index("data")
-            off = si * ns
-            if code_bits == 4:      # nibble slab: unpack once per shard
-                codes_shard = unpack_nibbles(codes_shard, K)
-            luts = build_lut(qs, C)
-            crude_lut = quantize_lut(luts, fast) if quantized else luts
-            crude = lut_sum(crude_lut, codes_shard, fast)  # (nq, ns)
-            gids = off + jnp.arange(ns, dtype=jnp.int32)
-            keep = (gids[None, :] < n) & alive[si]
-            if has_filter:
-                # filtered rows: crude +inf, so they can't bootstrap the
-                # eq. 2 threshold, can't pass it, and rank dead last —
-                # same exclusion semantics as the single-device engine
-                keep = keep & rest[0][None, :]
-            crude = jnp.where(keep, _sanitize(crude), jnp.inf)
-
-            # phase 1: local crude top-k + local full distances, merged
-            # globally before the threshold bootstrap (quantized mode
-            # mirrors the single-device decomposition: quantized crude
-            # + exact slow)
-            neg_c, li = jax.lax.top_k(-crude, k_loc)
-            cand_codes = jnp.take(codes_shard, li, axis=0)
-            if quantized:
-                full_cand = -neg_c + lut_sum(luts, cand_codes, ~fast)
-            else:
-                full_cand = lut_sum(luts, cand_codes)      # (nq, k_loc)
-            sv, _, sf = _gather_sorted(
-                (-neg_c, jnp.take(gids, li), full_cand), "data")
-            sv, sf = sv[:, :topk], sf[:, :topk]
-            # +inf crude slots (dead shards / tiny dbs) carry garbage
-            # full distances — exclude them from the far-element argmax
-            # (no-op when the merged top-k is fully populated)
-            far = jnp.argmax(jnp.where(jnp.isfinite(sv), sf, -jnp.inf),
-                             axis=1)
-            t = jnp.take_along_axis(sv, far[:, None], axis=1)[:, 0]
-            thr = t + sigma
-
-            # phase 2: prune against the global threshold, local refine
-            # top-k, merge by (full distance, global id)
-            passed = crude < thr[:, None]
-            slow = lut_sum(luts, codes_shard, ~fast)
-            ranked = jnp.where(passed, crude + slow, jnp.inf)
-            neg, li2 = jax.lax.top_k(-ranked, k_loc)
-            mv, mg = _gather_sorted((-neg, jnp.take(gids, li2)), "data")
-            pf = jax.lax.psum(
-                jnp.sum(passed.astype(jnp.float32), axis=1), "data") / n
-            return mg[:, :topk], mv[:, :topk], pf
-
-        specs = (P("data"),) + ((P("data"),) if has_filter else ())
         st = self.structure
-        fn = _bind_state(self.mesh, body,
-                         (self.C, st.fast_mask, st.sigma,
-                          self._alive_arr()),
-                         specs, (P(), P(), P()))
-        self._fns[key] = fn
-        return fn
+        fn = two_step_program(
+            self.mesh, n=self.n, topk=topk, K=self.C.shape[0],
+            fast=np.asarray(st.fast_mask), backend=self.backend,
+            quantized=self.lut_dtype == "int8", code_bits=self.code_bits,
+            has_filter=has_filter, block_q=self.block_q,
+            block_n=self.block_n, interpret=self.interpret)
+        self._fns[key] = _bind(self.mesh, fn, (self.C, st.fast_mask,
+                                               st.sigma, self._alive_arr()))
+        return self._fns[key]
 
     def search(self, queries, topk: Optional[int] = None, *,
                filter=None) -> SearchResult:
         """queries (nq, d) f32 -> SearchResult; ids and pass accounting
-        bitwise-identical to the single-device engine.  ``filter``:
-        optional (n,) boolean row predicate."""
+        bitwise-identical to the single-device engine of the same
+        backend.  ``filter``: optional (n,) boolean row predicate (the
+        jnp body serves it on either backend)."""
         topk = self.topk if topk is None else topk
         if filter is not None:
             pred = _shard_row_filter(self, filter)
@@ -461,6 +601,8 @@ class ShardedIVFTwoStep(_DeadShardMixin):
         self._list_lens = lens
         self.dead_shards = frozenset()
         self._fns = {}
+
+    backend = "jnp"                     # the shard body is jnp-only
 
     def _alive_rows(self) -> int:
         # shard s owns list rows [s*Ls, (s+1)*Ls); its reachable points
@@ -558,7 +700,8 @@ class ShardedIVFTwoStep(_DeadShardMixin):
                 full_cand = c_s + lut_sum(luts, cand_codes, ~fast)
             else:
                 full_cand = lut_sum(luts, cand_codes)      # (nq, k_loc)
-            sv, sp, sf = _gather_sorted((c_s, p_s, full_cand), "data")
+            with jax.named_scope("shard_merge"):
+                sv, sp, sf = _gather_sorted((c_s, p_s, full_cand), "data")
             sv, sf = sv[:, :topk], sf[:, :topk]
             far = jnp.argmax(jnp.where(jnp.isfinite(sv), sf, -jnp.inf),
                              axis=1)
@@ -571,9 +714,10 @@ class ShardedIVFTwoStep(_DeadShardMixin):
                 ranked = jnp.where(passed, crude + slow, jnp.inf)
                 r_s, k_s, i_s = jax.lax.sort((ranked, pos_key, safe),
                                              dimension=1, num_keys=2)
-                mv, _, mi = _gather_sorted(
-                    (r_s[:, :k_loc], k_s[:, :k_loc], i_s[:, :k_loc]),
-                    "data")
+                with jax.named_scope("shard_merge"):
+                    mv, _, mi = _gather_sorted(
+                        (r_s[:, :k_loc], k_s[:, :k_loc], i_s[:, :k_loc]),
+                        "data")
                 dist, idx = mv[:, :topk], mi[:, :topk]
             else:
                 # static compaction: merge the (crude, pos)-best cap
@@ -590,25 +734,28 @@ class ShardedIVFTwoStep(_DeadShardMixin):
                                                  axis=1)
                 f2 = lut_sum(luts, surv_codes)             # (nq, cap_loc)
                 i2 = jnp.take_along_axis(safe, col2, axis=1)
-                gv, _, gf, gi = _gather_sorted((c2, p2, f2, i2), "data")
+                with jax.named_scope("shard_merge"):
+                    gv, _, gf, gi = _gather_sorted((c2, p2, f2, i2),
+                                                   "data")
                 gv, gf, gi = gv[:, :cap], gf[:, :cap], gi[:, :cap]
                 ranked = jnp.where(jnp.isfinite(gv), gf, jnp.inf)
                 neg, cpos = jax.lax.top_k(-ranked, topk)
                 dist = -neg
                 idx = jnp.take_along_axis(gi, cpos, axis=1)
 
-            n_cand = jax.lax.psum(
-                jnp.sum(valid.astype(jnp.float32), axis=1), "data")
-            n_pass = jax.lax.psum(
-                jnp.sum(passed.astype(jnp.float32), axis=1), "data")
+            with jax.named_scope("shard_merge"):
+                n_cand = jax.lax.psum(
+                    jnp.sum(valid.astype(jnp.float32), axis=1), "data")
+                n_pass = jax.lax.psum(
+                    jnp.sum(passed.astype(jnp.float32), axis=1), "data")
             return idx, dist, n_cand, n_pass
 
         specs = (P("data"), P("data")) + ((P(),) if has_filter else ())
         st = self.structure
-        fn = _bind_state(self.mesh, body,
-                         (self.C, self.centroids, st.fast_mask, st.sigma,
-                          self._alive_arr()),
-                         specs, (P(), P(), P(), P()))
+        state = (self.C, self.centroids, st.fast_mask, st.sigma,
+                 self._alive_arr())
+        fn = _bind(self.mesh, _program(self.mesh, body, len(state), specs,
+                                       (P(), P(), P(), P())), state)
         self._fns[key] = fn
         return fn
 

@@ -475,18 +475,23 @@ class ThresholdStage:
         t = -jnp.take_along_axis(neg_c, far[:, None], axis=1)[:, 0]
         return t + sigma
 
-    def from_candidates(self, luts, codes, cand_vals, cand_idx, fast,
-                        sigma):
-        """Bootstrap from the fused crude kernel's running top-k (flat
-        pallas path): candidate full distances are crude + exact-slow
-        on either LUT dtype (the kernel already dequantized
+    def candidate_full(self, luts, codes, cand_vals, cand_idx, fast):
+        """Full distances of the fused crude kernel's candidates: crude
+        + exact-slow on either LUT dtype (the kernel already dequantized
         ``cand_vals`` to true-distance f32)."""
         from repro.index.base import lut_sum
         cand_codes = jnp.take(codes, cand_idx, axis=0)       # (nq,topk,K)
         if self.code_bits == 4:
             cand_codes = widen_codes(cand_codes, luts.shape[1],
                                      self.code_bits)
-        full_cand = cand_vals + lut_sum(luts, cand_codes, ~fast)
+        return cand_vals + lut_sum(luts, cand_codes, ~fast)
+
+    def from_candidates(self, luts, codes, cand_vals, cand_idx, fast,
+                        sigma):
+        """Bootstrap from the fused crude kernel's running top-k (flat
+        pallas path; ``candidate_full`` ranks the candidates)."""
+        full_cand = self.candidate_full(luts, codes, cand_vals, cand_idx,
+                                        fast)
         far = jnp.argmax(full_cand, axis=1)
         t = jnp.take_along_axis(cand_vals, far[:, None], axis=1)[:, 0]
         return t + sigma

@@ -133,7 +133,8 @@ def fit(key, xs, ys, icq_cfg, *, embed_kind="linear", num_classes=10,
             f"batch_size={bs} must divide over the {axis_size(mesh, 'data')}"
             "-way 'data' axis for the sharded epoch driver")
     step = joint.make_train_step(icq_cfg, state["embed_apply"], state["opt"],
-                                 mode, state["pq_mask"], tau, axis_name=axis)
+                                 mode, state["pq_mask"], tau, axis_name=axis,
+                                 unit=state["unit"])
     if ckpt_dir is not None:
         donate = False        # restart replay needs pre-epoch buffers
     epoch_fn = compile_epoch(step, icq_cfg.d, mesh=mesh, donate=donate)
@@ -156,7 +157,8 @@ def fit(key, xs, ys, icq_cfg, *, embed_kind="linear", num_classes=10,
                 print(f"  epoch {ep}: " + " ".join(
                     f"{name}={float(v):.4f}" for name, v in mets.items()))
     return joint.finalize(params, state["embed_apply"], var_state, icq_cfg,
-                          xs, mode=mode, encode_batch=encode_batch,
+                          xs, unit=state["unit"], mode=mode,
+                          encode_batch=encode_batch,
                           encode_backend=encode_backend)
 
 

@@ -37,6 +37,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import codebooks as cb
 from repro.core import embed as embed_mod
@@ -58,13 +59,61 @@ def _pq_support_mask(K: int, d: int):
     return m
 
 
+def fit_unit(emb) -> float:
+    """The scale the fit works in: the power of two that brings the RMS
+    entry of ``emb`` into [16, 32), 1.0 for an all-zero sample.
+
+    The fit's absolute constants (the AdamW step ``lr``, the soft
+    assignment's ``tau``, the prior's 1e-2 floors, the gradient clip)
+    act in the embedding's own units, so on rows of RMS entry 0.1 one step moves a
+    codeword by a large share of its length where on rows of RMS 20 it
+    barely moves it.  Training on ``emb * unit`` and dividing the unit
+    back out at export makes the fit the same, bit for bit, for any
+    power-of-two rescale of the rows; as a power of two the rescale is
+    exact, so rows already in range fit exactly as without it."""
+    rms = float(np.sqrt(np.mean(np.square(np.asarray(emb, np.float64)))))
+    if not (np.isfinite(rms) and rms > 0.0):
+        return 1.0
+    _, e = np.frexp(rms)                  # rms = f * 2**e, f in [0.5, 1)
+    return float(2.0 ** (5 - e))
+
+
+def _in_unit(emb, unit: float):
+    return emb if unit == 1.0 else emb * unit
+
+
+def _icq_supports(lam, theta, icq_cfg):
+    """(K, d) 0/1 supports of the ICQ warm start: the psi dimensions the
+    prior picks from the sample's variances (as ``finalize`` picks them)
+    split in runs over the ``num_fast`` fast codebooks, the others over
+    the slow ones.  Each codebook then owns its own dimensions, as in
+    PQ: the export's projection cuts nothing from it, and the encoder's
+    independent warm start is already the best code.  Full-width
+    residual codebooks lose most of their fit when cut to their side of
+    psi (a fifth of 64-bit PQ's recall on SIFT- and Deep-shaped rows)."""
+    K, nf = icq_cfg.num_codebooks, icq_cfg.num_fast
+    xi = np.asarray(icq_mod.compute_xi(lam, theta, icq_cfg,
+                                       min_dims=max(1, icq_cfg.d // K)))
+    mask = np.zeros((K, xi.shape[-1]), np.float32)
+    for books, dims in ((range(nf), np.flatnonzero(xi)),
+                        (range(nf, K), np.flatnonzero(~xi))):
+        if len(books):
+            for k, part in zip(books, np.array_split(dims, len(books))):
+                mask[k, part] = 1.0
+    return jnp.asarray(mask)
+
+
 def init_train_state(key, icq_cfg, *, embed_kind: str = "linear",
                      d_raw: Optional[int] = None, num_classes: int = 10,
                      img_hw: Optional[int] = None, channels: Optional[int] = None,
                      mode: str = "icq", lr: float = 1e-3,
                      sample_batch=None) -> Dict:
     """Build params + optimizer + variance state.  ``sample_batch`` (x, y)
-    seeds the codebooks from real embeddings (residual k-means)."""
+    seeds the codebooks from real embeddings (residual k-means; for ICQ
+    on the supports of ``_icq_supports``) and sets
+    the state's ``unit`` (``fit_unit``; 1.0 without a sample): codebooks,
+    prior and variance state live in embedding units times ``unit``, and
+    ``make_train_step`` and ``finalize`` take it as ``unit=``."""
     d, K, m = icq_cfg.d, icq_cfg.num_codebooks, icq_cfg.codebook_size
     k_embed, k_cb, k3 = jax.random.split(key, 3)
     embed_params, embed_apply = embed_mod.build_embedder(
@@ -72,13 +121,20 @@ def init_train_state(key, icq_cfg, *, embed_kind: str = "linear",
         img_hw=img_hw, channels=channels)
 
     theta0 = prior_mod.init_theta()
+    unit = 1.0
     if sample_batch is not None:
         emb0 = embed_apply(embed_params, sample_batch[0])
+        unit = fit_unit(emb0)
+        emb0 = _in_unit(emb0, unit)
+        lam0 = jnp.var(emb0, axis=0)
+        theta0 = prior_mod.init_theta_from_data(lam0)
         if mode == "pq":
             C0 = cb.init_pq(k_cb, emb0, K, m)
+        elif mode == "icq":
+            C0 = cb.init_residual(k_cb, emb0, K, m,
+                                  mask=_icq_supports(lam0, theta0, icq_cfg))
         else:
             C0 = cb.init_residual(k_cb, emb0, K, m)
-        theta0 = prior_mod.init_theta_from_data(jnp.var(emb0, axis=0))
     else:
         C0 = jax.random.normal(k_cb, (K, m, d), jnp.float32) * 0.1
 
@@ -93,6 +149,7 @@ def init_train_state(key, icq_cfg, *, embed_kind: str = "linear",
         "embed_apply": embed_apply,
         "mode": mode,
         "pq_mask": _pq_support_mask(K, d) if mode == "pq" else None,
+        "unit": unit,
     }
 
 
@@ -105,9 +162,12 @@ def _soft_xi(lam, theta, icq_cfg):
 
 def make_train_step(icq_cfg, embed_apply, opt: AdamW, mode: str,
                     pq_mask=None, tau: float = 1.0,
-                    axis_name: Optional[str] = None):
+                    axis_name: Optional[str] = None, *, unit: float):
     """Returns jit-able step(params, opt_state, var_state, batch) ->
     (params, opt_state, var_state, metrics).
+
+    ``unit``: the train state's ``unit``; every loss term sees the
+    embeddings times it.
 
     ``axis_name`` (optional): the mesh axis of a data-parallel region
     the step runs inside (``trainer.epoch`` shard_map driver).  Batch
@@ -117,7 +177,7 @@ def make_train_step(icq_cfg, embed_apply, opt: AdamW, mode: str,
     any extra synchronization."""
 
     def loss_fn(params, var_state, x, y):
-        emb = embed_apply(params["embed"], x)
+        emb = _in_unit(embed_apply(params["embed"], x), unit)
         # --- L^E ---
         logits = embed_mod.classify(params["embed"], emb)
         l_e = losses.classification_loss(logits, y)
@@ -171,11 +231,13 @@ def make_train_step(icq_cfg, embed_apply, opt: AdamW, mode: str,
     return step
 
 
-def finalize(params, embed_apply, var_state, icq_cfg, xs, *, mode="icq",
-             encode_batch: int = 8192, encode_backend: str = "auto",
-             interpret=None) -> ICQModel:
+def finalize(params, embed_apply, var_state, icq_cfg, xs, *, unit: float,
+             mode="icq", encode_batch: int = 8192,
+             encode_backend: str = "auto", interpret=None) -> ICQModel:
     """Export: hard-project codebooks (ICQ), ICM-encode the database
     through the tiled engine (DESIGN.md §9), build the search structure.
+    ``unit`` is the train state's: the structure is decided in the fit's
+    units, then codebooks, Lambda and sigma return to the embedding's.
 
     ``encode_batch`` chunks the database through one fixed-shape jitted
     embed+encode function — the ragged last chunk is zero-padded up to
@@ -198,6 +260,9 @@ def finalize(params, embed_apply, var_state, icq_cfg, xs, *, mode="icq",
         structure = icq_mod.ICQStructure(
             xi=xi, fast_mask=jnp.ones((C.shape[0],), bool),
             sigma=jnp.zeros(()))
+    if unit != 1.0:
+        C, lam = C / unit, lam / unit ** 2
+        structure = structure._replace(sigma=structure.sigma / unit ** 2)
 
     codes = encode_database(
         xs, C, embed_apply=embed_apply, embed_params=params["embed"],

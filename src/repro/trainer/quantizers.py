@@ -55,7 +55,7 @@ class JointQuantizer:
                           ys[:min(n, self.sample_size)]))
         st["step_fn"] = jax.jit(joint.make_train_step(
             self.icq_cfg, st["embed_apply"], st["opt"], self.mode,
-            st["pq_mask"], self.tau))
+            st["pq_mask"], self.tau, unit=st["unit"]))
         return st
 
     def step(self, state: Dict, batch) -> Dict:
@@ -68,7 +68,7 @@ class JointQuantizer:
     def finalize(self, state: Dict, xs) -> ICQModel:
         return joint.finalize(state["params"], state["embed_apply"],
                               state["var_state"], self.icq_cfg, xs,
-                              mode=self.mode)
+                              unit=state["unit"], mode=self.mode)
 
 
 @dataclasses.dataclass

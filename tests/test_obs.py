@@ -145,6 +145,7 @@ def test_a_request_id_links_its_submit_and_deliver_spans(traced):
     # the jnp engine runs no fused kernel
     assert attrs["repro.engine.search"]["crude_cols"] == 0
     assert attrs["repro.engine.search"]["refine_cols"] == 0
+    assert attrs["repro.engine.search"]["shards"] == 1      # no mesh
 
 
 def test_a_span_name_is_built_once():
@@ -224,3 +225,58 @@ def test_each_pass_contracts_over_its_own_codebooks(kind, tmp_path):
     assert attrs[0]["crude_cols"] == 2 * m
     assert attrs[0]["refine_cols"] == 6 * m
     assert attrs[0]["backend"] == "pallas"
+
+
+_SHARDED_SPAN_SCRIPT = """
+import json, sys
+import jax, numpy as np
+from repro.api import AnnEngine
+from repro.core.icq import ICQStructure
+from repro.index import make_index
+
+n, K, m, d, nq = 1030, 8, 16, 16, 4
+rng = np.random.default_rng(0)
+st = ICQStructure(xi=np.arange(d) < 8, fast_mask=np.isin(np.arange(K), (1, 5)),
+                  sigma=np.float32(1.0))
+idx = make_index("two-step", rng.integers(0, m, (n, K)).astype(np.uint8),
+                 rng.standard_normal((K, m, d)).astype(np.float32), st,
+                 topk=5, backend="pallas")
+engine = AnnEngine(idx, mesh=jax.make_mesh((4,), ("data",)))
+q = rng.standard_normal((nq, d)).astype(np.float32)
+engine.search(q)
+with jax.profiler.trace(sys.argv[1]):
+    engine.search(q)
+pd = jax.profiler.ProfileData.from_file(
+    __import__("glob").glob(sys.argv[1] + "/**/*.xplane.pb",
+                            recursive=True)[0])
+attrs = [dict(e.stats) for p in pd.planes for line in p.lines
+         for e in line.events if e.name == "repro.engine.search"]
+print("RESULT " + json.dumps(attrs))
+"""
+
+
+def test_a_sharded_search_span_names_its_shards_and_kernels(tmp_path):
+    """Over a four-device mesh (virtual CPU devices, in a subprocess:
+    this suite sees one) the ``repro.engine.search`` span of a search on
+    the fused kernels records the shard count, the backend and each
+    kernel's contraction width."""
+    import json
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = " ".join(filter(None, [
+        env.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=4"]))
+    env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", _SHARDED_SPAN_SCRIPT,
+                           str(tmp_path)], env=env, capture_output=True,
+                          text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-4000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT ")][-1]
+    attrs = json.loads(line[len("RESULT "):])
+    assert len(attrs) == 1
+    assert attrs[0]["shards"] == 4
+    assert attrs[0]["backend"] == "pallas"
+    assert attrs[0]["crude_cols"] == 2 * 16
+    assert attrs[0]["refine_cols"] == 6 * 16

@@ -34,7 +34,7 @@ SLOW = tuple(b for b in range(K) if b not in FAST)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -47,13 +47,18 @@ def one_chip():
     prev_cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev_cache)
     cc.reset_cache()
     if prev_log is None:
         os.environ.pop("TPU_LOG_DIR", None)
     else:
         os.environ["TPU_LOG_DIR"] = prev_log
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _kernel_call(name):
@@ -184,3 +189,106 @@ def test_search_kernels_keep_their_names_under_stage_scopes(one_chip, kind,
                 and f"f32[{NQ},{6 * m}]" in text)
     for stage, op_name in found.items():
         assert f"/{stage}/" in op_name
+
+
+# ------------------------------------------- the four-chip Deep-10M cell --
+# big-ann-benchmarks deep-10M over a v5e 2x2 host: 10M rows of d = 96,
+# 2.5M a chip, 64-bit codes (K = 8 of m = 256), query tiles of 64
+DEEP_N, DEEP_D, DEEP_LEARN, DEEP_POOL = 10_000_000, 96, 100_000, 10_000
+GB = 1e9
+
+
+def _bytes(compiled):
+    """(temporary, all) bytes of a compiled program on one chip."""
+    mem = compiled.memory_analysis()
+    temp = mem.temp_size_in_bytes
+    return temp, (temp + mem.argument_size_in_bytes
+                  + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def test_sharded_pallas_search_compiles_for_four_chips(v5e_2x2):
+    """The row-sharded two-step search on the fused kernels, one program
+    over the host's four chips at the cell's per-chip shapes.  Its
+    temporaries per chip stay under 2 GB: the dense crude matrix of a
+    64-query tile over 2.5M rows is 0.64 GB and the refine kernel's
+    +inf-padded copy of it another 0.64 GB; the rest is the kernels'
+    block-padded codes (20 MB) and the (64, k) merges."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.index.sharded import two_step_program
+
+    mesh = Mesh(np.asarray(v5e_2x2.devices).reshape(4), ("data",))
+    fn = two_step_program(mesh, n=DEEP_N, topk=TOPK, K=K,
+                          fast=np.isin(np.arange(K), FAST),
+                          backend="pallas", interpret=False)
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct((NQ, DEEP_D), jnp.float32, sharding=rep),
+            jax.ShapeDtypeStruct((K, M, DEEP_D), jnp.float32, sharding=rep),
+            jax.ShapeDtypeStruct((K,), jnp.bool_, sharding=rep),
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+            jax.ShapeDtypeStruct((4,), jnp.bool_, sharding=rep),
+            jax.ShapeDtypeStruct((DEEP_N, K), jnp.uint8, sharding=rows)]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    found = {m.group(3) for m in KERNEL_OP.finditer(text)}
+    assert found == {"crude", "refine"}
+    # each kernel narrowed to its pass's codebooks, on 2.5M rows a chip
+    assert (f"f32[{NQ},{2 * M}]" in text and f"f32[{NQ},{6 * M}]" in text)
+    assert f"u8[{DEEP_N // 4},{K}]" in text
+    assert re.search(r"all-gather(-start)?(\.\d+)? = ", text)
+    temp, _ = _bytes(compiled)
+    assert temp < 2 * GB, temp
+
+
+def _setup_program(name):
+    """(fn, [(shape, dtype), ...]) of one blocked set-up or check
+    program of the benchmark at deep-10M's sizes on chip 0."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import gen, reference, truth
+
+    f32, i32 = jnp.float32, jnp.int32
+    if name == "draw":
+        with open(os.path.join(root, "bench", "configs",
+                               "deep10m-icq64-twostep.json")) as f:
+            params = json.load(f)["assumed"]["generator"]
+        p = tuple(sorted((k, float(v)) for k, v in params.items()))
+        return (lambda key: gen._parts(
+            key, sizes=(DEEP_LEARN, DEEP_N, DEEP_POOL), d=DEEP_D, p=p),
+            [((2,), jnp.uint32)])
+    if name == "exact_neighbours":
+        blocks = -(-DEEP_POOL // 128)
+        return (lambda q, x, s, fr, bv, bi: truth._merge_rows(
+            q, x, s, fr, (bv, bi), k=TOPK, rows=truth.ROW_BLOCK, block=128),
+            [((DEEP_POOL, DEEP_D), f32), ((DEEP_N, DEEP_D), f32),
+             ((), i32), ((), i32), ((blocks, 128, TOPK), f32),
+             ((blocks, 128, TOPK), i32)])
+    if name == "icm_codes":
+        return (lambda x, C: reference.icm_codes(x, C, iters=3),
+                [((DEEP_N, DEEP_D), f32), ((K, M, DEEP_D), f32)])
+    if name == "two_step_block":
+        return (lambda q, c, C, fast, sigma, ans: reference.two_step_block(
+            q, c, C, fast, sigma, ans, topk=TOPK),
+            [((NQ, DEEP_D), f32), ((DEEP_N, K), i32),
+             ((K, M, DEEP_D), f32), ((K,), jnp.bool_), ((), f32),
+             ((NQ, TOPK), i32)])
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", ["draw", "exact_neighbours", "icm_codes",
+                                  "two_step_block"])
+def test_blocked_setup_fits_one_chip_at_deep10m(one_chip, name):
+    """The benchmark's set-up and check programs over the base rows hold
+    one block at a time: at 10M rows of d = 96 on one chip each needs at
+    most 2 GB of temporaries and 8 GB in all (the base itself is
+    3.84 GB)."""
+    fn, shapes = _setup_program(name)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    temp, total = _bytes(jax.jit(fn).lower(*args).compile())
+    assert temp <= 2 * GB and total <= 8 * GB, (temp, total)

@@ -131,6 +131,62 @@ def test_fit_produces_usable_model(train_data):
     assert mapv > 0.5
 
 
+@pytest.mark.parametrize("rms, unit", [(22.1, 1.0), (16.0, 1.0),
+                                       (31.99, 1.0), (32.0, 0.5),
+                                       (0.113, 256.0), (500.0, 1 / 16),
+                                       (0.0, 1.0)])
+def test_fit_unit_brings_the_rms_entry_into_16_to_32(rms, unit):
+    from repro.trainer.joint import fit_unit
+    assert fit_unit(np.full((8, 4), rms, np.float32)) == unit
+
+
+@pytest.mark.parametrize("scale", [1 / 256, 1.0, 256.0])
+def test_fit_is_the_same_for_rows_scaled_by_a_power_of_two(scale):
+    """The fit's absolute constants (lr, tau, the prior's floors) act in
+    the fit's unit, not the rows': rows times a power of two fit to the
+    same codes and fast set, with codebooks, Lambda and sigma scaled
+    exactly."""
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (640, 16))
+                   * jnp.linspace(0.2, 3.0, 16))
+    y = np.zeros((640,), np.int32)
+    cfg = ICQConfig(d=16, num_codebooks=4, codebook_size=16, num_fast=2)
+    kw = dict(embed_kind="identity", mode="icq", epochs=2, batch_size=128)
+    ref = fit(jax.random.PRNGKey(1), x, y, cfg, **kw)
+    got = fit(jax.random.PRNGKey(1), x * np.float32(scale), y, cfg, **kw)
+    np.testing.assert_array_equal(np.asarray(got.codes), np.asarray(ref.codes))
+    np.testing.assert_array_equal(np.asarray(got.structure.fast_mask),
+                                  np.asarray(ref.structure.fast_mask))
+    s = np.float32(scale)
+    np.testing.assert_array_equal(np.asarray(got.C), np.asarray(ref.C) * s)
+    np.testing.assert_array_equal(np.asarray(got.lam),
+                                  np.asarray(ref.lam) * s * s)
+    assert float(got.structure.sigma) == float(ref.structure.sigma) * scale ** 2
+
+
+@pytest.mark.parametrize("num_fast", [1, 2, 3])
+def test_icq_warm_start_gives_each_codebook_its_own_dimensions(num_fast):
+    """Fast codebooks start on psi, slow ones on the rest, no dimension
+    shared, and the fit exports that same psi and fast set: the export's
+    projection cuts nothing the warm start fit."""
+    from repro.trainer.joint import init_train_state
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(4), (1024, 16))
+                   * jnp.linspace(0.2, 3.0, 16))
+    y = np.zeros((1024,), np.int32)
+    cfg = ICQConfig(d=16, num_codebooks=4, codebook_size=16,
+                    num_fast=num_fast)
+    st = init_train_state(jax.random.PRNGKey(0), cfg, embed_kind="identity",
+                          d_raw=16, mode="icq", sample_batch=(x, y))
+    support = np.any(np.asarray(st["params"]["C"]) != 0, axis=1)    # (K, d)
+    assert support.any(axis=1).all()
+    assert (support.sum(axis=0) == 1).all()
+    psi = support[:num_fast].any(axis=0)
+    model = fit(jax.random.PRNGKey(0), x, y, cfg, embed_kind="identity",
+                mode="icq", epochs=1, batch_size=256)
+    np.testing.assert_array_equal(np.asarray(model.structure.xi), psi)
+    np.testing.assert_array_equal(np.asarray(model.structure.fast_mask),
+                                  np.arange(4) < num_fast)
+
+
 def test_epoch_batches_permutes_and_drops_tail(train_data):
     xtr, ytr = train_data
     xb, yb = epoch_batches(jax.random.PRNGKey(3), xtr, ytr, 128)
