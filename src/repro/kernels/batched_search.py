@@ -43,11 +43,24 @@ returned indices are bit-identical to a monolithic top-k over the full
 distance row, including the all-ties +inf tail when fewer than ``topk``
 points survive the margin test.
 
-Precision: f32 one-hot dots run at ``Precision.HIGHEST``.  On the TPU
-the default f32 dot rounds its operands to bfloat16, which would round
-every LUT entry; at HIGHEST a one-hot dot returns each selected entry
-exactly, so kernel distances match the jnp gather sums up to the order
-of the additions (a narrowed contraction leaves out only zero terms).
+Precision: the shared-codes kernels take an f32 table as an exact
+three-part bfloat16 split, made once a call in the wrapper
+(``split_lut``): hi = bf16(x), mid = bf16(x - hi), lo = bf16(x - hi -
+mid), each rounded to nearest and kept as f32 values.  Each part is
+exactly a bfloat16 and (hi + mid) + lo == x bit for bit: round to
+nearest leaves at most 16 bits after hi and 8 after mid.  Only an |x|
+below 2^-103 (about 1e-31) could lose bits: its lowest bits lie below
+f32's normal range, and the rounding flushes them to zero.  The parts
+of a query tile are stacked on its row axis, so the kernel issues one
+default-precision dot of (3 * blk_q, |books|*m) against the 0/1
+one-hot, which is exact in bfloat16 too, and sums the three row blocks
+as (S_hi + S_mid) + S_lo: the selected entries' sum to within a few
+f32 ulps, as ``Precision.HIGHEST`` gives it.  On a v5e the MXU takes
+only bf16 operands, and Mosaic lowers an f32 dot at HIGHEST to a
+six-pass emulation that reloads the one-hot's weights for every pass;
+the split takes one pass a part, and f32 operands holding bf16 values
+need no VPU pack.  The IVF slab kernels keep HIGHEST: Mosaic lowers
+their batched mat-vec to VPU multiply-adds, exact in f32 already.
 
 Both kernels accept arbitrary (non-divisible) n and nq: inputs are
 zero-padded up to the tile grid and pad columns are masked to +inf
@@ -115,27 +128,57 @@ from repro.kernels.stages import (check_quantized_args as
                                   unpack_nibble_tile as
                                   _unpack_nibble_tile)
 
-# exact one-hot selection of f32 LUT entries on the MXU (module docstring)
+# the slab kernels' f32 one-hot dot: exact selection of each entry
+# (module docstring); the shared-codes kernels split the table instead
 _F32_DOT = jax.lax.Precision.HIGHEST
+_ROWS = (((1,), (1,)), ((), ()))        # (rows, Km) x (blk_n, Km)^T
 
 
 def _dot_kwargs(lut):
     """int8 tables dot int8 x int8 into int32 (the MXU's quantized
-    form); f32 tables at HIGHEST, which selects each entry exactly."""
+    form); f32 tables, which only the slab kernels dot whole, at
+    HIGHEST, which selects each entry exactly."""
     if lut.dtype == jnp.int8:
         return dict(preferred_element_type=jnp.int32)
     return dict(precision=_F32_DOT, preferred_element_type=jnp.float32)
 
 
+def bf16_parts(x):
+    """f32 x -> (hi, mid, lo) f32 arrays, each exactly a bfloat16 value,
+    with (hi + mid) + lo == x bit for bit (module docstring)."""
+    def bf16(v):
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+
+    hi = bf16(x)
+    mid = bf16(x - hi)
+    return hi, mid, bf16(x - hi - mid)
+
+
+def split_lut(lut, block_q: int):
+    """(nq_pad, Km) f32 tables -> (3 * nq_pad, Km): each block_q-row
+    query tile becomes its hi, mid and lo rows stacked, the block the
+    shared-codes kernels pin as (3 * block_q, Km)."""
+    nq_pad, km = lut.shape
+    parts = jnp.stack([p.reshape(-1, block_q, km) for p in bf16_parts(lut)],
+                      axis=1)
+    return parts.reshape(3 * nq_pad, km)
+
+
 def _tile_distances(codes, lut, K: int, m: int, books):
     """Shared-codes tile distances: codes (blk_n, K) int32 against the
-    (blk_q, len(books)*m) LUT tile of the listed codebooks ->
-    (blk_q, blk_n) f32 | int32, one narrowed one-hot and one dot.  (One
-    m-wide dot per listed codebook ran as fast here and slower in the
-    slab kernels on a v5e.)"""
+    LUT tile of the listed codebooks -> (blk_q, blk_n) f32 | int32, one
+    narrowed one-hot and one dot.  An int8 tile is (blk_q, |books|*m);
+    an f32 tile is ``split_lut``'s (3 * blk_q, |books|*m), dotted at
+    default precision and its row blocks summed (S_hi + S_mid) + S_lo.
+    (One m-wide dot per listed codebook ran as fast here and slower in
+    the slab kernels on a v5e.)"""
     onehot = flat_onehot(codes, K, m, lut.dtype, books)
-    return jax.lax.dot_general(lut, onehot, (((1,), (1,)), ((), ())),
-                               **_dot_kwargs(lut))
+    if lut.dtype == jnp.int8:
+        return jax.lax.dot_general(lut, onehot, _ROWS, **_dot_kwargs(lut))
+    parts = jax.lax.dot_general(lut, onehot, _ROWS,
+                                preferred_element_type=jnp.float32)
+    blk_q = parts.shape[0] // 3
+    return (parts[:blk_q] + parts[blk_q:2 * blk_q]) + parts[2 * blk_q:]
 
 
 def _merge_tile(vals_ref, idx_ref, ranked, gidx, topk: int):
@@ -163,9 +206,9 @@ def _crude_topk_kernel(codes_ref, lut_ref, *refs,
     codes = codes_ref[...].astype(jnp.int32)     # widen packed codes per-tile
     if nibble:
         codes = _unpack_nibble_tile(codes)       # (blk_n, K) fast-scan mode
-    lut = lut_ref[...]                  # (blk_q, |books|*m) f32 | int8
-    blk_q = lut.shape[0]
+    lut = lut_ref[...]     # f32 (3 * blk_q, |books|*m) parts | int8 (blk_q, .)
     acc = _tile_distances(codes, lut, K, m, books)    # (blk_q, blk_n) MXU
+    blk_q = acc.shape[0]
     if quantized:
         scale_ref, offset_ref, *refs = refs
         # rescale to true-distance f32: codebooks outside the fast set
@@ -194,10 +237,10 @@ def _refine_topk_kernel(codes_ref, lut_ref, crude_ref, thr_ref,
     codes = codes_ref[...].astype(jnp.int32)     # widen packed codes per-tile
     if nibble:
         codes = _unpack_nibble_tile(codes)
-    lut = lut_ref[...]                           # (blk_q, |books|*m) f32 slow
+    lut = lut_ref[...]                # (3 * blk_q, |books|*m) f32 parts
     crude = crude_ref[...]                       # (blk_q, blk_n) f32
     thr = thr_ref[...]                           # (blk_q, 1) f32 = t + sigma
-    blk_q = lut.shape[0]
+    blk_q = crude.shape[0]
     slow = _tile_distances(codes, lut, K, m, books)
     full = crude + slow                               # eq. 1 refinement
 
@@ -252,13 +295,15 @@ def crude_topk_pallas(codes, lut_flat, lut_scale=None, lut_offset=None, *,
                   pl.BlockSpec((block_q, topk), lambda qi, ni: (qi, 0)))
     crude_shape = (jax.ShapeDtypeStruct((nq_pad, n_pad), jnp.float32),)
     crude_spec = (pl.BlockSpec((block_q, block_n), lambda qi, ni: (qi, ni)),)
+    lut_rows = block_q if quantized else 3 * block_q
     in_specs = [
         pl.BlockSpec((block_n, Kc), lambda qi, ni: (ni, 0)),
-        pl.BlockSpec((block_q, Km), lambda qi, ni: (qi, 0)),  # pinned
+        pl.BlockSpec((lut_rows, Km), lambda qi, ni: (qi, 0)),  # pinned
     ]
-    operands = [_pad_to(codes, n_pad),
-                _pad_to(lut_flat if quantized
-                        else lut_flat.astype(jnp.float32), nq_pad)]
+    lut_p = _pad_to(lut_flat, nq_pad)
+    if not quantized:
+        lut_p = split_lut(lut_p.astype(jnp.float32), block_q)
+    operands = [_pad_to(codes, n_pad), lut_p]
     if quantized:
         col = pl.BlockSpec((block_q, 1), lambda qi, ni: (qi, 0))
         in_specs += [col, col]
@@ -520,7 +565,8 @@ def refine_topk_pallas(codes, lut_flat, crude, thresholds, *, topk: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, Kc), lambda qi, ni: (ni, 0)),
-            pl.BlockSpec((block_q, Km), lambda qi, ni: (qi, 0)),  # pinned
+            pl.BlockSpec((3 * block_q, Km),
+                         lambda qi, ni: (qi, 0)),              # pinned
             pl.BlockSpec((block_q, block_n), lambda qi, ni: (qi, ni)),
             pl.BlockSpec((block_q, 1), lambda qi, ni: (qi, 0)),
         ],
@@ -530,7 +576,8 @@ def refine_topk_pallas(codes, lut_flat, crude, thresholds, *, topk: int,
         ),
         interpret=interpret,
     )(_pad_to(codes, n_pad),
-      _pad_to(lut_flat.astype(jnp.float32), nq_pad), crude_p, thr)
+      split_lut(_pad_to(lut_flat.astype(jnp.float32), nq_pad), block_q),
+      crude_p, thr)
     return vals[:nq], idx[:nq]
 
 

@@ -317,3 +317,203 @@ def test_kernel_columns_are_the_operand_widths(fast, quantized, code_bits,
     assert kernel_columns(mask, K, m, quantized=quantized,
                           code_bits=code_bits) == (books[0] * m,
                                                    books[1] * m)
+
+
+# ------------------------------------------- the exact bf16 LUT split ----
+# The shared-codes kernels dot an f32 table as three bfloat16 parts at
+# default precision (``batched_search.split_lut``).  On the CPU that
+# dot runs at full f32, so a part that is not exactly a bfloat16 would
+# pass every kernel test here and lose bits on the TPU: the split is
+# held to exactness on its own.
+
+def _split_values():
+    """f32 values spanning 1e-30 to 1e30 with both signs, zeros, powers
+    of two, full 24-bit mantissas, and ties at half a bf16 ulp of hi
+    and of mid."""
+    rng = np.random.default_rng(17)
+    sign = rng.choice([-1.0, 1.0], 300_000)
+    wide = sign[:200_000] * 10.0 ** rng.uniform(-30, 30, 200_000)
+    mant = rng.integers(2 ** 23, 2 ** 24, 100_000)
+    full = sign[200_000:] * np.ldexp(mant, rng.integers(-40, 40, 100_000))
+    # mantissa bits 8-15 and 0-7 each exactly half a bf16 ulp: a tie
+    # when hi rounds and again when mid does
+    tie_mant = (rng.integers(128, 256, 4096) << 16) | 0x8080
+    ties = np.ldexp(tie_mant, rng.integers(-90, 70, 4096) - 23)
+    pow2 = np.ldexp(1.0, np.arange(-99, 100))
+    return np.concatenate([wide, full, ties, -ties, pow2, -pow2,
+                           [0.0, -0.0]]).astype(np.float32)
+
+
+def test_bf16_split_parts_are_bf16_and_sum_back_exactly():
+    from repro.kernels.batched_search import bf16_parts
+    x = _split_values()
+    assert np.all(np.isfinite(x)) and np.abs(x[x != 0]).min() >= 1e-30
+    parts = [np.asarray(p) for p in jax.jit(bf16_parts)(jnp.asarray(x))]
+    for p in parts:
+        np.testing.assert_array_equal(
+            p, np.asarray(jnp.asarray(p).astype(jnp.bfloat16)
+                          .astype(jnp.float32)))
+    back = (parts[0] + parts[1]) + parts[2]
+    np.testing.assert_array_equal(back, x)
+    # bit for bit; -0.0 comes back as +0.0, which sums alike
+    nz = x != 0
+    np.testing.assert_array_equal(back[nz].view(np.uint32),
+                                  x[nz].view(np.uint32))
+    # the ties took the split through all three parts
+    assert np.count_nonzero(parts[2]) > 0
+
+
+def test_split_lut_stacks_each_query_tiles_parts():
+    from repro.kernels.batched_search import bf16_parts, split_lut
+    lut = jnp.asarray(_split_values()[:6 * 40].reshape(6, 40))
+    got = np.asarray(split_lut(lut, 2))
+    hi, mid, lo = (np.asarray(p) for p in bf16_parts(lut))
+    for t in range(3):
+        rows = slice(2 * t, 2 * t + 2)
+        np.testing.assert_array_equal(
+            got[6 * t:6 * t + 6], np.concatenate([hi[rows], mid[rows],
+                                                  lo[rows]]))
+
+
+def _full_mantissa_luts(key, nq, K, m):
+    """Tables of mixed signs whose entries carry full 24-bit mantissas,
+    so dropping any bf16 part of the split shows in the sums."""
+    rng = np.random.default_rng(int(jax.random.randint(key, (), 0, 2 ** 30)))
+    mant = rng.integers(2 ** 23, 2 ** 24, (nq, K, m))
+    sign = rng.choice([-1.0, 1.0], (nq, K, m))
+    return (sign * np.ldexp(mant, rng.integers(-25, -21, (nq, K, m)))
+            ).astype(np.float32)
+
+
+def _gather64(luts, codes, books):
+    """float64 sums of the listed codebooks' entries, and the sums of
+    their magnitudes (the scale of the f32 rounding), each (nq, n)."""
+    L, c = np.asarray(luts, np.float64), np.asarray(codes)
+    terms = [L[:, b, c[:, b]] for b in books]
+    return sum(terms), sum(np.abs(t) for t in terms)
+
+
+@pytest.mark.parametrize("kernel", ["crude", "refine"])
+@pytest.mark.parametrize("narrowed", [False, True])
+def test_shared_codes_kernels_sum_full_mantissa_tables(key, kernel,
+                                                       narrowed):
+    """Distances within 4 f32 ulps of the float64 gather sum, the ulp
+    taken at the terms' absolute sum (mixed signs cancel, and an ulp of
+    a cancelled sum bounds no f32 summation); ids those of the jnp
+    path, on data without ties."""
+    from repro.index.base import lut_sum
+    n, nq, K, m, topk = 300, 5, 8, 32, 8
+    fast = np.isin(np.arange(K), (1, 5))
+    codes = jax.random.randint(key, (n, K), 0, m)
+    luts = _full_mantissa_luts(jax.random.fold_in(key, 1), nq, K, m)
+    opts = dict(block_q=2, block_n=128, interpret=True)
+    summed = ~fast if kernel == "refine" else fast
+    books = tuple(np.flatnonzero(summed)) if narrowed else tuple(range(K))
+    mask = summed if narrowed else np.ones(K, bool)
+    flat = luts[:, list(books)].reshape(nq, -1)
+    want64, scale = _gather64(luts, codes, books)
+    want32 = lut_sum(jnp.asarray(luts), codes, jnp.asarray(mask))
+    if kernel == "crude":
+        crude, dist, idx = ops.batched_crude_topk(
+            codes, flat, topk, books=books if narrowed else None, **opts)
+        got = np.asarray(crude)
+        _, idx0 = jax.lax.top_k(-want32, topk)
+        rows = np.asarray(idx0)
+    else:
+        crude0 = lut_sum(jnp.asarray(luts), codes, jnp.asarray(fast))
+        thr = jnp.quantile(crude0, 0.5, axis=1)
+        dist, idx = ops.batched_refine_topk(
+            codes, flat, crude0, thr, topk,
+            books=books if narrowed else None, **opts)
+        _, idx0 = jax.lax.top_k(
+            -jnp.where(crude0 < thr[:, None], crude0 + want32, jnp.inf),
+            topk)
+        rows = np.asarray(idx0)
+        pick = np.take_along_axis
+        c64 = np.asarray(crude0, np.float64)
+        want64 = pick(c64, rows, 1) + pick(want64, rows, 1)
+        scale = np.abs(pick(c64, rows, 1)) + pick(scale, rows, 1)
+        got = np.asarray(dist)
+    np.testing.assert_array_equal(np.asarray(idx), rows)
+    if kernel == "crude":
+        np.testing.assert_array_equal(
+            np.asarray(dist), np.take_along_axis(got, rows, 1))
+    ulp = np.spacing(scale.astype(np.float32)).astype(np.float64)
+    assert np.max(np.abs(got - want64) / ulp) <= 4.0
+
+
+# ------------------------------------------------ the lowering witness ----
+# Which f32 one-hot dots run at HIGHEST, read off the kernels' jaxprs:
+# the shared-codes crude and refine kernels take the split at default
+# precision; the IVF slab kernels (lowered to VPU multiply-adds) and the
+# ICM encode keep HIGHEST.
+
+def _kernel_dot_precisions(fn, *args):
+    """For each pallas_call in fn's jaxpr, the precision of every
+    dot_general inside its kernel."""
+    kernels = []
+
+    def subjaxprs(v):
+        if hasattr(v, "eqns"):
+            yield v
+        elif hasattr(v, "jaxpr") and hasattr(v.jaxpr, "eqns"):
+            yield v.jaxpr
+        elif isinstance(v, (tuple, list)):
+            for x in v:
+                yield from subjaxprs(x)
+
+    def walk(jaxpr, dots):
+        for eqn in jaxpr.eqns:
+            inner = dots
+            if eqn.primitive.name == "pallas_call":
+                inner = []
+                kernels.append(inner)
+            elif eqn.primitive.name == "dot_general" and dots is not None:
+                dots.append(eqn.params["precision"])
+            for v in eqn.params.values():
+                for j in subjaxprs(v):
+                    walk(j, inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return kernels
+
+
+def _witness_call(name):
+    from repro.kernels import batched_search as bs
+    from repro.kernels.icm_encode import icm_encode_pallas
+    n, nq, K, m, topk, nc = 256, 4, 4, 16, 5, 128
+    f32 = jnp.float32
+    codes = jnp.zeros((n, K), jnp.uint8)
+    slab = jnp.zeros((nq, nc, K), jnp.uint8)
+    ids = jnp.zeros((nq, nc), jnp.int32)
+    lut, lut2 = jnp.zeros((nq, K * m), f32), jnp.zeros((nq, 2 * m), f32)
+    crude, thr = jnp.zeros((nq, n), f32), jnp.zeros((nq,), f32)
+    opts = dict(topk=topk, block_q=2, block_n=128)
+    return {
+        "crude": (lambda: bs.crude_topk_pallas(codes, lut, **opts)),
+        "crude_narrowed": (lambda: bs.crude_topk_pallas(
+            codes, lut2, books=(1, 3), **opts)),
+        "refine": (lambda: bs.refine_topk_pallas(codes, lut, crude, thr,
+                                                 **opts)),
+        "refine_narrowed": (lambda: bs.refine_topk_pallas(
+            codes, lut2, crude, thr, books=(0, 2), **opts)),
+        "ivf_crude": (lambda: bs.ivf_crude_topk_pallas(slab, ids, lut,
+                                                       **opts)),
+        "ivf_refine": (lambda: bs.ivf_refine_topk_pallas(
+            slab, lut, crude[:, :nc], thr, **opts)),
+        "icm_encode": (lambda: icm_encode_pallas(
+            jnp.zeros((n, 8), f32), jnp.zeros((n, K), jnp.int32),
+            jnp.zeros((K, m, 8), f32), block_n=128)),
+    }[name]
+
+
+@pytest.mark.parametrize("name,highest", [
+    ("crude", False), ("crude_narrowed", False), ("refine", False),
+    ("refine_narrowed", False), ("ivf_crude", True), ("ivf_refine", True),
+    ("icm_encode", True)])
+def test_f32_kernel_dots_run_at_highest_only_off_the_split(name, highest):
+    kernels = _kernel_dot_precisions(_witness_call(name))
+    assert len(kernels) == 1 and kernels[0]
+    at_highest = [p is not None and jax.lax.Precision.HIGHEST in p
+                  for p in kernels[0]]
+    assert all(at_highest) if highest else not any(at_highest)

@@ -181,12 +181,15 @@ def test_search_kernels_keep_their_names_under_stage_scopes(one_chip, kind,
     text = jax.jit(search).lower(*args).compile().as_text()
     found = {m.group(3): m.group(5) for m in KERNEL_OP.finditer(text)}
     assert set(found) == {"crude", "refine"}
+    # the shared-codes kernels take each query's table as three bf16
+    # parts, stacked on the row axis (``batched_search.split_lut``)
+    rows = 3 * NQ if kind == "two-step" else NQ
     if traced:
-        assert f"f32[{NQ},{kk * m}]" in text
+        assert f"f32[{rows},{kk * m}]" in text
     else:
         # fast codebooks (1, 5) in crude, the other six in refine
-        assert (f"f32[{NQ},{2 * m}]" in text
-                and f"f32[{NQ},{6 * m}]" in text)
+        assert (f"f32[{rows},{2 * m}]" in text
+                and f"f32[{rows},{6 * m}]" in text)
     for stage, op_name in found.items():
         assert f"/{stage}/" in op_name
 
@@ -233,8 +236,10 @@ def test_sharded_pallas_search_compiles_for_four_chips(v5e_2x2):
     text = compiled.as_text()
     found = {m.group(3) for m in KERNEL_OP.finditer(text)}
     assert found == {"crude", "refine"}
-    # each kernel narrowed to its pass's codebooks, on 2.5M rows a chip
-    assert (f"f32[{NQ},{2 * M}]" in text and f"f32[{NQ},{6 * M}]" in text)
+    # each kernel narrowed to its pass's codebooks (three bf16 parts a
+    # query row), on 2.5M rows a chip
+    assert (f"f32[{3 * NQ},{2 * M}]" in text
+            and f"f32[{3 * NQ},{6 * M}]" in text)
     assert f"u8[{DEEP_N // 4},{K}]" in text
     assert re.search(r"all-gather(-start)?(\.\d+)? = ", text)
     temp, _ = _bytes(compiled)
